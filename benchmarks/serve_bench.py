@@ -45,7 +45,7 @@ import jax.numpy as jnp
 from repro.configs import REGISTRY
 from repro.core.pact import quantize_signed
 from repro.models.api import build
-from repro.models.common import QuantConfig
+from repro.launch.serve import fake_quant
 from repro.serve import Request, SamplingParams, ServeEngine
 from repro.serve.deploy import (default_deploy_bits, default_deploy_layout,
                                 to_serving_params, weight_stream_bytes)
@@ -343,8 +343,8 @@ def main():
                     help="draft tokens per speculative round")
     args = ap.parse_args()
 
-    cfg = REGISTRY[args.arch].tiny(dtype="float32").with_quant(
-        QuantConfig(mode="fake", n_bits=8, act_bits=8))
+    cfg = REGISTRY[args.arch].tiny(dtype="float32")
+    cfg = cfg.with_quant(fake_quant(cfg))
     api = build(cfg)
     params = api.init(jax.random.PRNGKey(0))
     args.deploy_bits = default_deploy_bits(args.backend, args.deploy_bits)

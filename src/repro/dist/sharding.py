@@ -98,6 +98,21 @@ def _batch_entry(mesh):
     return dp[0] if len(dp) == 1 else tuple(dp)
 
 
+def data_size(mesh=None) -> int:
+    """Devices along the data-parallel axes of ``mesh`` (1 without one)."""
+    mesh = mesh if mesh is not None else get_mesh()
+    return 1 if mesh is None else _group_size(_batch_entry(mesh), mesh)
+
+
+def rows_entry(n: int, mesh=None):
+    """The spec entry that splits a dim of ``n`` rows over the data axes
+    (None when they do not divide it, or there are none)."""
+    mesh = mesh if mesh is not None else get_mesh()
+    if mesh is None or n % data_size(mesh):
+        return None
+    return _batch_entry(mesh)
+
+
 # logical axis name -> mesh axes (resolved against the active mesh)
 _LOGICAL = {
     "batch": _batch_entry,
@@ -442,6 +457,33 @@ def shard_params_tree(params):
         lambda x, ps: jax.lax.with_sharding_constraint(
             x, NamedSharding(mesh, ps)),
         params, specs)
+
+
+def run_kernel(kern, *args, in_specs, out_specs, psum_axis=None):
+    """Call a Pallas kernel under the active mesh.
+
+    XLA cannot partition a Mosaic kernel, so on a multi-device mesh the
+    call runs inside ``shard_map``: each device applies ``kern`` to the
+    blocks ``in_specs`` give it (operands laid out otherwise are moved
+    to match), and ``psum_axis`` sums partial products over that axis.
+    Mesh axes the specs name but the mesh lacks are ignored.  Without a
+    mesh, or on one device, ``kern`` runs as it is."""
+    mesh = get_mesh()
+    if mesh is None or math.prod(mesh.shape.values()) == 1:
+        return kern(*args)
+
+    def fit(ps):
+        def keep(e):
+            axes = e if isinstance(e, tuple) else (e,)
+            return e if all(a in mesh.shape for a in axes) else None
+        return P(*[keep(e) for e in ps])
+
+    body = kern
+    if psum_axis is not None and psum_axis in mesh.shape:
+        body = lambda *a: jax.lax.psum(kern(*a), psum_axis)
+    return jax.shard_map(body, mesh=mesh,
+                         in_specs=tuple(fit(s) for s in in_specs),
+                         out_specs=fit(out_specs), check_vma=False)(*args)
 
 
 # --------------------------------------------------------------------------

@@ -1,4 +1,8 @@
-"""Pallas TPU kernels for BWQ inference (validated in interpret mode).
+"""Pallas TPU kernels for BWQ inference.
+
+Each is checked against its jnp oracle (``ref``) in interpret mode, and
+compiles for a TPU v5e at phi3-mini-3.8b widths (tests/test_tpu_compile.py;
+``chip_smoke.py`` runs them compiled on the chip).
 
 bitplane_matmul — bit-plane-sliced mixed-precision matmul (paper layout)
 packed_matmul   — int8/int4 per-WB-scale dequant matmul (deployment)

@@ -21,7 +21,10 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .pallas_utils import fit_block, pad_dim, resolve_interpret, round_up
+from jax.experimental.pallas import tpu as pltpu
+
+from .pallas_utils import (expand_lut, fit_block, lut_by_col_block, pad_dim,
+                           resolve_interpret, round_up, tile_block)
 
 
 def _kernel(x_ref, planes_ref, sign_ref, mask_ref, scale_ref, o_ref, *,
@@ -36,6 +39,7 @@ def _kernel(x_ref, planes_ref, sign_ref, mask_ref, scale_ref, o_ref, *,
     bn = o_ref.shape[1]
 
     def unpack(packed):                             # (bk//8, bn) -> (bk, bn)
+        packed = packed.astype(jnp.int32)           # shifts on 32-bit lanes
         parts = [((packed >> r) & 1) for r in range(8)]
         st = jnp.stack(parts, axis=1)               # (bk//8, 8, bn)
         return st.reshape(block_k, bn)
@@ -44,16 +48,14 @@ def _kernel(x_ref, planes_ref, sign_ref, mask_ref, scale_ref, o_ref, *,
     mag = jnp.zeros((block_k, bn), jnp.float32)
     for b in range(n_bits):
         plane = unpack(planes_ref[b]).astype(jnp.float32)
-        m = mask_ref[b].astype(jnp.float32)         # (bk//wbr, bn//wbc)
-        m = jnp.repeat(jnp.repeat(m, wbr, axis=0), wbc, axis=1)
+        m = expand_lut(mask_ref[0, b], wbr, wbc)    # (bk, bn) from the tile
         mag = mag + (2.0 ** b) * plane * m
 
     sign = 1.0 - 2.0 * unpack(sign_ref[...]).astype(jnp.float32)
     if per_block:
         # per-WB effective scale (serving layout): /(2^n - 1) and each
         # block's power-of-two rescale factor are pre-folded into the LUT
-        s = jnp.repeat(jnp.repeat(scale_ref[...], wbr, axis=0), wbc, axis=1)
-        w = sign * mag * s
+        w = sign * mag * expand_lut(scale_ref[0], wbr, wbc)
     else:
         w = sign * mag * (scale_ref[0] / (2.0 ** n_bits - 1.0))
     o_ref[...] += jnp.dot(x, w, preferred_element_type=jnp.float32)
@@ -96,15 +98,22 @@ def bitplane_matmul(x, planes_packed, sign_packed, mask, scale, *,
         scale = pad_dim(pad_dim(scale, 0, kp // wbr), 1, np_ // wbc)
 
     block_m = fit_block(min(block_m, mp), mp, 8)
-    block_n = fit_block(min(block_n, np_), np_, wbc)
-    block_k = fit_block(min(block_k, kp), kp, unit_k)
+    block_n = tile_block(block_n, np_, math.lcm(wbc, 128))
+    # K tiles: x's lane dim (128), the packed planes' sublane dim (8 byte
+    # rows = 64 weight rows) and the LUT's sublane dim (8 WB rows)
+    block_k = tile_block(block_k, kp, math.lcm(unit_k, 128, 64, 8 * wbr))
     grid = (mp // block_m, np_ // block_n, kp // block_k)
+    cb = block_n // wbc
 
     kern = functools.partial(_kernel, n_bits=n_bits, wbr=wbr, wbc=wbc,
                              block_k=block_k, per_block=per_block)
-    scale_spec = pl.BlockSpec((block_k // wbr, block_n // wbc),
-                              lambda i, j, kk: (kk, j)) if per_block \
-        else pl.BlockSpec((1,), lambda i, j, kk: (0,))
+    mask = lut_by_col_block(mask, cb)               # (nN, n_bits, GR, cb)
+    if per_block:
+        scale = lut_by_col_block(scale, cb)         # (nN, GR, cb)
+        scale_spec = pl.BlockSpec((1, block_k // wbr, cb),
+                                  lambda i, j, kk: (j, kk, 0))
+    else:
+        scale_spec = pl.BlockSpec(memory_space=pltpu.SMEM)
     y = pl.pallas_call(
         kern,
         grid=grid,
@@ -113,8 +122,8 @@ def bitplane_matmul(x, planes_packed, sign_packed, mask, scale, *,
             pl.BlockSpec((n_bits, block_k // 8, block_n),
                          lambda i, j, kk: (0, kk, j)),
             pl.BlockSpec((block_k // 8, block_n), lambda i, j, kk: (kk, j)),
-            pl.BlockSpec((n_bits, block_k // wbr, block_n // wbc),
-                         lambda i, j, kk: (0, kk, j)),
+            pl.BlockSpec((1, n_bits, block_k // wbr, cb),
+                         lambda i, j, kk: (j, 0, kk, 0)),
             scale_spec,
         ],
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, kk: (i, j)),

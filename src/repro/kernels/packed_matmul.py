@@ -15,12 +15,14 @@ K case (an extra zero WB row absorbs the unpaired nibble).
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .pallas_utils import fit_block, pad_dim, resolve_interpret, round_up
+from .pallas_utils import (expand_lut, fit_block, lut_by_col_block, pad_dim,
+                           resolve_interpret, round_up, tile_block)
 
 
 def _kernel8(x_ref, w_ref, s_ref, o_ref, *, wbr, wbc):
@@ -32,7 +34,7 @@ def _kernel8(x_ref, w_ref, s_ref, o_ref, *, wbr, wbc):
 
     x = x_ref[...].astype(jnp.float32)
     w = w_ref[...].astype(jnp.float32)
-    s = jnp.repeat(jnp.repeat(s_ref[...], wbr, axis=0), wbc, axis=1)
+    s = expand_lut(s_ref[0], wbr, wbc)
     o_ref[...] += jnp.dot(x, w * s, preferred_element_type=jnp.float32)
 
 
@@ -44,13 +46,13 @@ def _kernel4(x_ref, w_ref, s_ref, o_ref, *, wbr, wbc, block_k):
         o_ref[...] = jnp.zeros_like(o_ref)
 
     x = x_ref[...].astype(jnp.float32)
-    packed = w_ref[...]                                  # (bk//2, bn) uint8
-    lo = (packed & 0xF).astype(jnp.int32)
-    hi = ((packed >> 4) & 0xF).astype(jnp.int32)
-    lo = jnp.where(lo >= 8, lo - 16, lo)
-    hi = jnp.where(hi >= 8, hi - 16, hi)
+    # nibbles as sign-extended int32 (two's complement in [-8, 7]);
+    # shifting on 32-bit lanes, since Mosaic has no 8-bit shifts
+    packed = w_ref[...].astype(jnp.int32)                # (bk//2, bn)
+    lo = (packed << 28) >> 28
+    hi = (packed << 24) >> 28
     w = jnp.stack([lo, hi], axis=1).reshape(block_k, packed.shape[1])
-    s = jnp.repeat(jnp.repeat(s_ref[...], wbr, axis=0), wbc, axis=1)
+    s = expand_lut(s_ref[0], wbr, wbc)
     o_ref[...] += jnp.dot(x, w.astype(jnp.float32) * s,
                           preferred_element_type=jnp.float32)
 
@@ -93,8 +95,10 @@ def packed_matmul(x, w_int, scale, *, bits: int = 8, wbr: int = 8,
         raise ValueError(bits)
 
     block_m = fit_block(min(block_m, mp), mp, 8)
-    block_n = fit_block(min(block_n, n), n, wbc)
-    block_k = fit_block(min(block_k, kp), kp, unit_k)
+    block_n = tile_block(block_n, n, math.lcm(wbc, 128))
+    # K tiles: x's lane dim (128), the LUT's sublane dim (8 WB rows) and,
+    # for int4, the nibble-pair rows must all stay tile-aligned
+    block_k = tile_block(block_k, kp, math.lcm(unit_k, 128, 8 * wbr))
     grid = (mp // block_m, n // block_n, kp // block_k)
     common = dict(
         grid=grid,
@@ -102,8 +106,9 @@ def packed_matmul(x, w_int, scale, *, bits: int = 8, wbr: int = 8,
         out_shape=jax.ShapeDtypeStruct((mp, n), jnp.float32),
         interpret=interpret,
     )
-    s_spec = pl.BlockSpec((block_k // wbr, block_n // wbc),
-                          lambda i, j, kk: (kk, j))
+    scale = lut_by_col_block(scale, block_n // wbc)
+    s_spec = pl.BlockSpec((1, block_k // wbr, block_n // wbc),
+                          lambda i, j, kk: (j, kk, 0))
     x_spec = pl.BlockSpec((block_m, block_k), lambda i, j, kk: (i, kk))
     if bits == 8:
         kern = functools.partial(_kernel8, wbr=wbr, wbc=wbc)

@@ -36,6 +36,7 @@ from ..optim.optimizers import adamw
 from ..train.state import TrainState
 from ..train.step import (freeze_mask, microbatched_value_and_grad,
                           quant_reg_loss)
+from .cache import enable_compile_cache
 from .mesh import make_production_mesh
 
 
@@ -355,6 +356,7 @@ def main():
     ap.add_argument("--out", default="experiments/dryrun")
     ap.add_argument("--force", action="store_true")
     args = ap.parse_args()
+    enable_compile_cache()
     archs = sorted(REGISTRY) if args.arch == "all" else args.arch.split(",")
     cells = None if args.cell == "all" else args.cell.split(",")
     meshes = {"single": [False], "multi": [True],

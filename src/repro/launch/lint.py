@@ -18,10 +18,11 @@ from ..analysis import (ShapeOnlyMesh, lint_engine, production_mesh_shape,
                         validate_checkpoint)
 from ..configs import REGISTRY
 from ..models.api import build
-from ..models.common import QuantConfig
 from ..serve import ServeEngine
 from ..serve.deploy import (default_deploy_bits, default_deploy_layout,
                             to_serving_params)
+from .cache import enable_compile_cache
+from .serve import fake_quant
 
 
 def build_engine(arch: str, backend: str, deploy_bits: int = 0,
@@ -38,7 +39,7 @@ def build_engine(arch: str, backend: str, deploy_bits: int = 0,
     cfg = REGISTRY[arch]
     if tiny:
         cfg = cfg.tiny(dtype="float32")
-    cfg = cfg.with_quant(QuantConfig(mode="fake", n_bits=8, act_bits=8))
+    cfg = cfg.with_quant(fake_quant(cfg))
     api = build(cfg)
     params = api.init(jax.random.PRNGKey(0))
     deploy_bits = default_deploy_bits(backend, deploy_bits)
@@ -101,6 +102,7 @@ def main(argv=None) -> int:
                     help="additionally validate a checkpoint directory's "
                          "shard manifests (CK1-CK3 contracts)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     engine = build_engine(args.arch, args.backend, args.deploy_bits,
                           args.layout, args.kv_bits, args.page_size,

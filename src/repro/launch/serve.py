@@ -19,16 +19,17 @@ prompt tokens a common system prompt across the batch, and
 JSON for CI smoke assertions.
 """
 import argparse
+import dataclasses
 
 import jax
 import jax.numpy as jnp
 
 from ..configs import REGISTRY
 from ..models.api import build
-from ..models.common import QuantConfig
 from ..serve import Request, SamplingParams, ServeEngine
 from ..serve.deploy import (default_deploy_bits, default_deploy_layout,
-                            to_serving_params)
+                            deploy_from_seed, to_serving_params)
+from .cache import enable_compile_cache
 
 
 def _prompts(cfg, args):
@@ -44,6 +45,12 @@ def _prompts(cfg, args):
             jax.random.PRNGKey(2),
             (args.batch, args.prompt_len, cfg.d_model)) * 0.1
     return batch
+
+
+def fake_quant(cfg):
+    """The launchers' QAT setting: 8-bit fake quantization of weights and
+    activations, on the config's own weight-block geometry."""
+    return dataclasses.replace(cfg.quant, mode="fake", n_bits=8, act_bits=8)
 
 
 def resolve_ckpt_dir(path: str, step: int = -1) -> str:
@@ -151,11 +158,12 @@ def main():
                     help="with --ckpt on a manager dir: the step to load "
                          "(-1 = latest)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = REGISTRY[args.arch]
     if args.tiny:
         cfg = cfg.tiny(dtype="float32")
-    cfg = cfg.with_quant(QuantConfig(mode="fake", n_bits=8, act_bits=8))
+    cfg = cfg.with_quant(fake_quant(cfg))
     api = build(cfg)
     args.deploy_bits = default_deploy_bits(args.backend, args.deploy_bits)
     if args.ckpt:
@@ -170,14 +178,14 @@ def main():
               f"int{args.deploy_bits or 8} serving weights "
               f"(peak {stats['peak_host_bytes']} B host vs "
               f"{stats['dense_tree_bytes']} B dense tree)")
+    elif args.deploy_bits:
+        # leaf by leaf from the seed: the f32 master tree never exists
+        layout = default_deploy_layout(args.backend)
+        params = deploy_from_seed(api, jax.random.PRNGKey(0),
+                                  args.deploy_bits, layout=layout)
+        print(f"deployed: {layout} int{args.deploy_bits} serving weights")
     else:
         params = api.init(jax.random.PRNGKey(0))
-        if args.deploy_bits:
-            layout = default_deploy_layout(args.backend)
-            params = to_serving_params(params, args.deploy_bits,
-                                       layout=layout)
-            print(f"deployed: {layout} int{args.deploy_bits} "
-                  f"serving weights")
 
     batch = _prompts(cfg, args)
     if args.shared_prefix:

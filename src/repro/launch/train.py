@@ -5,6 +5,7 @@ TPU slice the same entrypoint drives the full config under the production
 mesh (jax.distributed initialization is environment-driven).
 """
 import argparse
+import dataclasses
 
 import jax
 
@@ -15,6 +16,7 @@ from ..models.api import build
 from ..models.common import QuantConfig
 from ..optim import adamw, cosine_schedule
 from ..train import Trainer, TrainerConfig
+from .cache import enable_compile_cache
 from .mesh import make_production_mesh
 
 
@@ -35,12 +37,13 @@ def main():
     ap.add_argument("--mesh", default="none",
                     choices=["none", "single", "multi"])
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = REGISTRY[args.arch]
     if args.tiny:
         cfg = cfg.tiny(dtype="float32")
-    cfg = cfg.with_quant(QuantConfig(mode=args.quant_mode, n_bits=8,
-                                     act_bits=args.act_bits)) \
+    cfg = cfg.with_quant(dataclasses.replace(
+        cfg.quant, mode=args.quant_mode, n_bits=8, act_bits=args.act_bits)) \
         if args.quant_mode != "none" else \
         cfg.with_quant(QuantConfig(mode="none"))
     api = build(cfg)

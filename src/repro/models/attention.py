@@ -8,9 +8,10 @@ from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from ..core.quantize import pack_int4, unpack_int4
-from ..dist.sharding import constraint
+from ..dist.sharding import constraint, data_size, get_mesh, run_kernel
 from ..kernels.paged_attention import paged_attention
 from ..kernels.pallas_utils import fit_block
 from ..kernels.ref import paged_attention_ref
@@ -196,13 +197,46 @@ def paged_attn(q, store: Dict, table, kv_len, *, window=0,
     args = (qg, store["k"], store["v"], store.get("k_scale"),
             store.get("v_scale"), table, kv_len)
     if backend == "fused":
-        out = paged_attention(*args, window=win, softcap=attn_softcap)
+        out = _fused_over_heads(args, win, attn_softcap)
     elif backend == "ref":
         out = paged_attention_ref(*args, window=win, softcap=attn_softcap)
     else:
         raise ValueError(f"paged_attn executes 'fused' or 'ref', "
                          f"got {backend!r}")
     return out.reshape(b, s, h, dh).astype(q.dtype)
+
+
+def _fused_over_heads(args, win, attn_softcap):
+    """The paged-attention kernel, each device taking its share of the KV
+    heads when the mesh's 'model' axis divides them (the decode state's
+    layout, ``cache_pspecs``); otherwise every device takes all heads.
+
+    Meshes with data axes wider than 1 are refused: they shard the page
+    pool, and block tables hold global page ids, so no device could read
+    its slots' pages alone."""
+    q, k, v, k_scale, v_scale, table, kv_len = args
+    mesh = get_mesh()
+    if data_size(mesh) > 1:
+        raise NotImplementedError(
+            f"the fused paged-attention kernel runs on meshes of data "
+            f"size 1 only (this mesh: {dict(mesh.shape)}); the page pool "
+            f"is sharded over the data axes and block tables hold global "
+            f"page ids.  Use attn_backend='gather' on this mesh.")
+    m = dict(mesh.shape).get("model", 1) if mesh is not None else 1
+    heads = "model" if m > 1 and q.shape[1] % m == 0 else None
+    quant = k_scale is not None
+    ops = [q, k, v] + ([k_scale, v_scale] if quant else []) \
+        + [table, kv_len] + ([] if win is None else [win])
+    specs = [P(None, heads)] + [P(None, None, heads)] * (4 if quant else 2)
+    specs += [P()] * (len(ops) - len(specs))
+
+    def kern(q, k, v, *rest):
+        ks, vs, rest = (rest[0], rest[1], rest[2:]) if quant \
+            else (None, None, rest)
+        return paged_attention(q, k, v, ks, vs, rest[0], rest[1],
+                               window=rest[2] if len(rest) > 2 else None,
+                               softcap=attn_softcap)
+    return run_kernel(kern, *ops, in_specs=specs, out_specs=P(None, heads))
 
 
 def _as_pool(leaf, page: int):

@@ -37,11 +37,13 @@ from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from ..core.bitrep import QuantizedTensor, compose, from_float
 from ..core.blocking import BlockingSpec
 from ..core.fakequant import FakeQuantTensor, fq_compose, fq_from_float
 from ..core.pact import pact_sym_quant
+from ..dist.sharding import rows_entry, run_kernel
 
 MATMUL_BACKENDS = ("dense", "pallas", "ref", "bitplane")
 _BACKEND_STACK = ["dense"]
@@ -156,6 +158,29 @@ def qdense(w: Any, dtype=None) -> jnp.ndarray:
     return w
 
 
+def _run_split(kern, split: str, x2, *weight):
+    """``kern(x2, *weight)`` with its work split over the mesh's 'model'
+    axis the way the weight is laid out (``ServingWeight.split``): by
+    output columns, or by input rows with the partial products summed.
+    Every weight array (payload or per-WB LUT) splits on the same dim.
+    The rows of ``x2`` (batch x tokens) split over the data axes where
+    they divide, so each data replica multiplies only its own rows."""
+    lead = lambda a, spec: P(*([None] * (a.ndim - len(spec)) + spec))
+    rows = rows_entry(x2.shape[0])
+    if split == "col":
+        specs = [P(rows)] + [lead(a, ["model"]) for a in weight]
+        return run_kernel(kern, x2, *weight, in_specs=specs,
+                          out_specs=P(rows, "model"))
+    if split == "row":
+        specs = [P(rows, "model")] + [lead(a, ["model", None])
+                                      for a in weight]
+        return run_kernel(kern, x2, *weight, in_specs=specs,
+                          out_specs=P(rows), psum_axis="model")
+    return run_kernel(kern, x2, *weight,
+                      in_specs=[P(rows)] + [P()] * len(weight),
+                      out_specs=P(rows))
+
+
 def _qmatmul_packed(x: jnp.ndarray, sw, backend: str) -> jnp.ndarray:
     """x (..., K) @ packed ServingWeight (Kp, Np) -> (..., N)."""
     from ..kernels.packed_matmul import packed_matmul
@@ -166,8 +191,10 @@ def _qmatmul_packed(x: jnp.ndarray, sw, backend: str) -> jnp.ndarray:
     lead = x.shape[:-1]
     x2 = x.reshape(-1, x.shape[-1])
     if backend == "pallas":
-        y = packed_matmul(x2, pk.w_int, pk.scale, bits=pk.bits,
-                          wbr=pk.wbr, wbc=pk.wbc)
+        y = _run_split(
+            lambda x, w, s: packed_matmul(x, w, s, bits=pk.bits, wbr=pk.wbr,
+                                          wbc=pk.wbc),
+            sw.split, x2, pk.w_int, pk.scale)
     else:                                                  # 'ref'
         y = packed_matmul_ref(x2, pk.w_int, pk.scale, pk.bits,
                               pk.wbr, pk.wbc)
@@ -187,9 +214,11 @@ def _qmatmul_bitplane(x: jnp.ndarray, sw, backend: str) -> jnp.ndarray:
         y = bitplane_matmul_ref(x2, bl.planes_packed, bl.sign_packed,
                                 bl.mask, bl.scale, bl.wbr, bl.wbc)
     else:                                      # 'bitplane' / 'pallas'
-        y = bitplane_matmul(x2, bl.planes_packed, bl.sign_packed, bl.mask,
-                            bl.scale, n_bits=bl.n_bits, wbr=bl.wbr,
-                            wbc=bl.wbc)
+        y = _run_split(
+            lambda x, p, g, m, s: bitplane_matmul(
+                x, p, g, m, s, n_bits=bl.n_bits, wbr=bl.wbr, wbc=bl.wbc),
+            sw.split, x2, bl.planes_packed, bl.sign_packed, bl.mask,
+            bl.scale)
     return y[:, :n].reshape(*lead, n).astype(x.dtype)
 
 

@@ -10,7 +10,6 @@ The router is kept in float32 and outside BWQ quantization (DESIGN.md §5).
 """
 from __future__ import annotations
 
-import functools
 from typing import Dict, Tuple
 
 import jax
@@ -40,20 +39,10 @@ def _gm_fwd(x, w, group_sizes):
 def _gm_bwd(res, dy):
     x, w, gs = res
     dx = jax.lax.ragged_dot(dy, jnp.swapaxes(w, 1, 2), gs)
-    if hasattr(jax.lax, "RaggedDotDimensionNumbers"):
-        dnums = jax.lax.RaggedDotDimensionNumbers(
-            dot_dimension_numbers=(((0,), (0,)), ((), ())),
-            lhs_ragged_dimensions=[0], rhs_group_dimensions=[])
-        dw = jax.lax.ragged_dot_general(x, dy, gs, dnums)
-    else:
-        # Older jax has no ragged-contracting mode: mask tokens into their
-        # group via one-hot and contract.  Materializes (T, E, K) — fine at
-        # the small-scale sizes that run on these jax versions.
-        e = w.shape[0]
-        gid = jnp.repeat(jnp.arange(e), gs, total_repeat_length=x.shape[0])
-        onehot = jax.nn.one_hot(gid, e, dtype=x.dtype)      # (T, E)
-        xg = onehot[:, :, None] * x[:, None, :]             # (T, E, K)
-        dw = jnp.einsum("tek,tn->ekn", xg, dy)
+    dnums = jax.lax.RaggedDotDimensionNumbers(
+        dot_dimension_numbers=(((0,), (0,)), ((), ())),
+        lhs_ragged_dimensions=[0], rhs_group_dimensions=[])
+    dw = jax.lax.ragged_dot_general(x, dy, gs, dnums)
     return dx.astype(x.dtype), dw.astype(w.dtype), None
 
 
@@ -322,13 +311,9 @@ def _moe_forward_sharded(p: Dict, x: jnp.ndarray, top_k: int, mesh
                       P(None, mdl, None))
     in_specs = (P(dpa, None, None), P()) + w_in_specs
     out_specs = (P(dpa, None, None), P())
-    if hasattr(jax, "shard_map"):
-        smap = functools.partial(jax.shard_map, check_vma=False)
-    else:  # older jax: experimental namespace, check_rep spelling
-        from jax.experimental.shard_map import shard_map as _sm
-        smap = functools.partial(_sm, check_rep=False)
-    out, aux = smap(local_moe, mesh=mesh, in_specs=in_specs,
-                    out_specs=out_specs)(x, rw, wg, wu, wd)
+    out, aux = jax.shard_map(local_moe, mesh=mesh, in_specs=in_specs,
+                             out_specs=out_specs, check_vma=False)(
+        x, rw, wg, wu, wd)
 
     if "shared_gate" in p:
         hs = jax.nn.silu(qmatmul(x, p["shared_gate"])) \

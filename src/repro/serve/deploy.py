@@ -26,6 +26,7 @@ streams 2 planes, not a dtype-wide word).
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Tuple
 
 import jax
@@ -49,6 +50,11 @@ class ServingWeight:
     shape: Tuple[int, ...] = dataclasses.field(metadata=dict(static=True))
     spec: BlockingSpec = dataclasses.field(metadata=dict(static=True))
     bits: int = dataclasses.field(default=8, metadata=dict(static=True))
+    # How a mesh-placed engine laid the payload over the 'model' axis:
+    # 'col' (output columns), 'row' (input rows) or '' (whole).  Set only
+    # by the engine's placement (``serve.engine._mark_splits``); a kernel
+    # call under that mesh splits its work the same way.
+    split: str = dataclasses.field(default="", metadata=dict(static=True))
 
 
 @jax.tree_util.register_dataclass
@@ -77,6 +83,7 @@ class BitplaneServingWeight:
     # the qmatmul activation recorder can key captured statistics back to
     # the stacked deployed leaf.  Empty outside calibration.
     tag: str = dataclasses.field(default="", metadata=dict(static=True))
+    split: str = dataclasses.field(default="", metadata=dict(static=True))
 
 
 def _integer_grid(w, scale, bitwidth, spec, n_bits, bits):
@@ -224,6 +231,58 @@ def _serving_params_from_ckpt(path: str, bits: int, layout: str,
         reader.close()
 
 
+def deploy_from_seed(api, key, bits: int = 8,
+                     layout: str = "packed") -> Any:
+    """Serving weights for ``api``'s model drawn from ``key``, built
+    without ever holding the f32 master tree.
+
+    Plain leaves (embeddings, norms, clip values) come from one program
+    that runs the model's init with every quantized leaf dead.  Each
+    quantized leaf of ``api.abstract_params()`` is drawn one layer slice
+    at a time inside a ``lax.map``: ``make_weight``'s N(0, 1/fan_in)
+    draw, then ``_convert_leaf``.  So at most one (K, N) f32 slice is live
+    on the device, and each distinct leaf shape compiles once."""
+    flat, treedef = jax.tree_util.tree_flatten(api.abstract_params(),
+                                               is_leaf=_is_quant)
+    plain = [i for i, x in enumerate(flat) if not _is_quant(x)]
+
+    def init_plain(k):
+        leaves = jax.tree_util.tree_flatten(api.init(k), is_leaf=_is_quant)[0]
+        return [leaves[i] for i in plain]
+
+    out = dict(zip(plain, jax.jit(init_plain)(key)))
+    for i, leaf in enumerate(flat):
+        if i in out:
+            continue
+        lead = leaf.shape[:-2]
+        keys = jax.random.split(jax.random.fold_in(key, i),
+                                int(np.prod(lead, dtype=np.int64)))
+        sw = _draw_layers(keys, leaf.shape[-2:], api.cfg.quant, bits, layout)
+        sw = jax.tree_util.tree_map(
+            lambda a: a.reshape(*lead, *a.shape[1:]), sw)
+        out[i] = dataclasses.replace(sw, shape=tuple(leaf.shape))
+    tree = jax.tree_util.tree_unflatten(treedef, [out[i]
+                                                  for i in range(len(flat))])
+    _check_contracts(tree)
+    return tree
+
+
+@functools.partial(jax.jit, static_argnames=("kn", "qc", "bits", "layout"))
+def _draw_layers(keys, kn, qc, bits, layout):
+    """One deployed (K, N) slice per key, stacked along a new lead dim."""
+    from ..models.common import make_weight
+    return jax.lax.map(
+        lambda k: _convert_leaf(make_weight(k, kn, qc), bits, layout), keys)
+
+
+def _check_contracts(tree: Any) -> None:
+    from ..analysis.contracts import validate_serving_tree
+    bad = [f for f in validate_serving_tree(tree) if f.severity == "error"]
+    if bad:
+        raise ValueError("deployment produced a contract-violating tree:\n"
+                         + "\n".join(f.format() for f in bad[:8]))
+
+
 def to_serving_params(params: Any, bits: int = 8, layout: str = "packed",
                       validate: bool = True, template: Any = None,
                       stats: Any = None) -> Any:
@@ -255,13 +314,7 @@ def to_serving_params(params: Any, bits: int = 8, layout: str = "packed",
             lambda x: _convert_leaf(x, bits, layout), params,
             is_leaf=_is_quant)
     if validate:
-        from ..analysis.contracts import validate_serving_tree
-        bad = [f for f in validate_serving_tree(out)
-               if f.severity == "error"]
-        if bad:
-            raise ValueError(
-                "deployment produced a contract-violating tree:\n"
-                + "\n".join(f.format() for f in bad[:8]))
+        _check_contracts(out)
     return out
 
 

@@ -80,6 +80,47 @@ def _roundup64(n: int) -> int:
     return -(-n // 64) * 64
 
 
+def _mark_splits(params, specs, mesh):
+    """Record on each deployed leaf how ``specs`` placed it over the
+    mesh's 'model' axis (``split``: 'col', 'row' or ''), so the kernel
+    call splits its work the same way (``models.common._run_split``).
+
+    A split is kept only where every shard holds whole weight blocks of
+    an unpadded dim, so a kernel can run on each shard alone.  The
+    engine's placement is the one place a split is set: a leaf placed
+    any other way keeps '' and its kernel runs on the whole weight."""
+    from .deploy import BitplaneServingWeight, ServingWeight
+    deployed = (ServingWeight, BitplaneServingWeight)
+    m = dict(mesh.shape).get("model", 1)
+
+    def mark(leaf, ps):
+        if not isinstance(leaf, deployed):
+            return leaf
+        packed = isinstance(leaf, ServingWeight)
+        payload, pspec = (leaf.w_int, ps.w_int) if packed \
+            else (leaf.planes, ps.planes)
+        gr, gc = leaf.scale.shape[-2:]
+        entries = [e if isinstance(e, tuple) else (e,)
+                   for e in tuple(pspec) + (None,) * (payload.ndim
+                                                      - len(pspec))]
+        split = ""
+        if m > 1 and "model" in entries[-1]:
+            n = leaf.shape[-1]
+            if n == gc * leaf.spec.wb_cols and gc % m == 0:
+                split = "col"
+        elif m > 1 and "model" in entries[-2]:
+            rows = payload.shape[-2]
+            per_row = 8 if not packed else (2 if leaf.bits == 4 else 1)
+            kp = gr * leaf.spec.wb_rows
+            if leaf.shape[-2] == kp == rows * per_row and gr % m == 0 \
+                    and rows % m == 0:
+                split = "row"
+        return dataclasses.replace(leaf, split=split)
+
+    return jax.tree_util.tree_map(
+        mark, params, specs, is_leaf=lambda x: isinstance(x, deployed))
+
+
 @dataclasses.dataclass
 class ServeEngine:
     api: ModelAPI
@@ -225,7 +266,10 @@ class ServeEngine:
         and every jitted entry point slices back (``_unpad_params``)
         before the model ever sees the tree."""
         if not self.padded_sharding:
-            return self._place(tree, param_pspecs)
+            with use_mesh(self.mesh):
+                specs = param_pspecs(tree)
+            return _mark_splits(self._place(tree, param_pspecs), specs,
+                                self.mesh)
         with use_mesh(self.mesh):
             specs = param_pspecs(tree, pad=True)
         if self._pad_shapes is None:
@@ -233,10 +277,11 @@ class ServeEngine:
             # draft_params share every leaf shape with params
             self._pad_shapes = [tuple(x.shape)
                                 for x in jax.tree_util.tree_leaves(tree)]
-        return jax.tree_util.tree_map(
+        placed = jax.tree_util.tree_map(
             lambda x, s: jax.device_put(
                 pad_leaf(x, s, self.mesh), NamedSharding(self.mesh, s)),
             tree, specs)
+        return _mark_splits(placed, specs, self.mesh)
 
     def _unpad_params(self, params):
         """In-graph mask side of padded placement: slice each leaf back to
@@ -362,6 +407,16 @@ class ServeEngine:
             tokens, index = put["tokens"], put["index"]
         with use_mesh(self.mesh):
             return self._verify_j(self.params, tokens, state, index)
+
+    def compiled_text(self, step: str, *args) -> str:
+        """Optimized HLO of the ``step`` program ('prefill_chunk' or
+        'decode') for the arguments :meth:`prefill_chunk_at` /
+        :meth:`decode` would pass after the params; a program that
+        already ran comes from the jit cache, not a second compile."""
+        fn = {"prefill_chunk": self._prefill_chunk_j,
+              "decode": self._decode_j}[step]
+        with use_mesh(self.mesh):
+            return fn.lower(self.params, *args).compile().as_text()
 
     # ---- preemption / prefix-cache state plumbing ------------------------
     def park_slot(self, state: Any, slot: int, pages) -> Dict[str, Any]:

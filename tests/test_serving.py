@@ -27,7 +27,8 @@ from repro.configs import REGISTRY
 from repro.models.api import build
 from repro.models.common import QuantConfig, make_weight
 from repro.serve import Request, SamplingParams, ServeEngine
-from repro.serve.deploy import serving_compose, to_serving_params
+from repro.serve.deploy import (deploy_from_seed, serving_compose,
+                                to_serving_params)
 
 KEY = jax.random.PRNGKey(3)
 
@@ -294,6 +295,39 @@ def test_int4_pack_roundtrip_odd_and_even_k(mode, k):
 
 
 # ---------------------------------------------------------------------------
+# leaf-wise deployment from a seed
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("arch", ["phi3-mini-3.8b", "granite-moe-3b-a800m"])
+def test_deploy_from_seed_matches_whole_tree_layout(arch):
+    """The leaf-wise deploy yields the tree the whole-tree path would:
+    same structure, shapes and dtypes; plain leaves are the model's own
+    init; each quantized layer slice is an N(0, 1/K) draw; same seed,
+    same bytes."""
+    api = build(REGISTRY[arch].tiny(dtype="float32"))
+    key = jax.random.PRNGKey(0)
+    got = deploy_from_seed(api, key, 8)
+    want = to_serving_params(api.init(key), 8)
+    assert jax.tree_util.tree_structure(got) == \
+        jax.tree_util.tree_structure(want)
+    for g, w in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        assert (g.shape, g.dtype) == (w.shape, w.dtype)
+    init = jax.jit(api.init)(key)
+    np.testing.assert_array_equal(np.asarray(got["embed"]),
+                                  np.asarray(init["embed"]))
+    w = np.asarray(serving_compose(got["layers"]["attn"]["wq"], jnp.float32))
+    k = w.shape[-2]
+    std = w.reshape(w.shape[0], -1).std(axis=1)
+    np.testing.assert_allclose(std, 1 / np.sqrt(k), rtol=0.1)
+    assert not np.allclose(w[0], w[1])        # layers drawn independently
+    again = deploy_from_seed(api, key, 8)
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(again)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+# ---------------------------------------------------------------------------
 # sharded serving (2 host devices, subprocess)
 # ---------------------------------------------------------------------------
 
@@ -364,3 +398,71 @@ def test_sharded_decode_matches_single_device():
     assert out.returncode == 0, out.stderr[-2000:]
     assert "SHARDED_OK" in out.stdout
     assert "SHARDED_PAGED_OK" in out.stdout
+
+
+_SHARDED_KERNELS_SCRIPT = r"""
+import jax, jax.numpy as jnp, numpy as np
+from repro.configs import REGISTRY
+from repro.models.api import build
+from repro.dist.sharding import use_mesh
+from repro.launch.mesh import make_mesh
+from repro.serve import ServeEngine, Request, SamplingParams
+from repro.serve.deploy import deploy_from_seed
+
+assert jax.device_count() == 2, jax.device_count()
+cfg = REGISTRY["phi3-mini-3.8b"].tiny(dtype="float32", d_model=256,
+                                      d_ff=512, n_heads=2, n_kv_heads=2,
+                                      d_head=128)
+api = build(cfg)
+toks = jax.random.randint(jax.random.PRNGKey(1), (3, 12), 0,
+                          cfg.vocab).astype(jnp.int32)
+for layout, kv_bits in (("packed", 8), ("bitplane", 4)):
+    params = deploy_from_seed(api, jax.random.PRNGKey(0), 8, layout=layout)
+    kw = dict(kv_quant_bits=kv_bits, attn_backend="fused", page_size=8,
+              prefill_chunk=8,
+              backend="bitplane" if layout == "bitplane" else "pallas")
+    reqs = lambda: [Request(uid=i, inputs={"tokens": toks[i:i+1]},
+                            sampling=SamplingParams(max_new_tokens=5),
+                            arrival=i) for i in range(3)]
+    ref = ServeEngine(api, params, **kw).serve(reqs(), n_slots=3)
+    with use_mesh(make_mesh((1, 2), ("data", "model"))):
+        eng = ServeEngine(api, params, **kw)
+        res = eng.serve(reqs(), n_slots=3)
+    lay = eng.params["layers"]
+    splits = {n: lay[g][n].split for g in ("attn", "mlp") for n in lay[g]}
+    assert splits == {"wq": "col", "wk": "col", "wv": "col", "wo": "row",
+                      "w_gate": "col", "w_up": "col", "w_down": "row"}, splits
+    assert all(res[i].tokens == ref[i].tokens for i in range(3)), layout
+    # data=2: each replica's matmul kernels take its own rows; the fused
+    # attention kernel cannot read a data-sharded page pool and refuses
+    with use_mesh(make_mesh((2, 1), ("data", "model"))):
+        res = ServeEngine(api, params, **dict(kw, attn_backend="gather")
+                          ).serve(reqs(), n_slots=3)
+        try:
+            ServeEngine(api, params, **kw).serve(reqs(), n_slots=3)
+            raise AssertionError("fused attention ran on data=2")
+        except NotImplementedError as e:
+            assert "data size 1" in str(e), e
+    assert all(res[i].tokens == ref[i].tokens for i in range(3)), layout
+print("SHARDED_KERNELS_OK")
+"""
+
+
+def test_sharded_kernels_match_single_device():
+    """Under a (data=1, model=2) mesh the Pallas matmuls split by output
+    columns or input rows and the paged-attention kernel by KV heads
+    (``shard_map``, since Mosaic kernels cannot be auto-partitioned);
+    under (data=2, model=1) the matmuls split their rows.  The served
+    tokens equal the single-device engine's, and the fused attention
+    kernel refuses a data-sharded mesh."""
+    env = dict(os.environ,
+               JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=2",
+               PYTHONPATH=os.pathsep.join(
+                   [os.path.join(os.path.dirname(__file__), "..", "src")] +
+                   sys.path))
+    out = subprocess.run([sys.executable, "-c", _SHARDED_KERNELS_SCRIPT],
+                         env=env, capture_output=True, text=True,
+                         timeout=900)
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert "SHARDED_KERNELS_OK" in out.stdout

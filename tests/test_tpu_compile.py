@@ -1,0 +1,71 @@
+"""The serving kernels compile for a TPU v5e at phi3-mini-3.8b widths.
+
+No chip is needed: the TPU compiler targets a described ``v5e:2x2``
+topology and refuses what the chip would refuse (tiling, VMEM, Mosaic
+legalization), which interpret mode never checks.  The topology is
+described inside a fixture, never at import, so every pytest-xdist
+worker collects the same tests and only the one running them loads the
+TPU library.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels import bitplane_matmul, packed_matmul, paged_attention
+
+K, N = 3072, 8192                        # d_model -> d_ff
+KV, DH, PAGE, SLOTS, BLOCKS = 32, 96, 16, 4, 68
+POOL = 1 + SLOTS * BLOCKS                # trash page + worst case
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compile(fn, one_chip, *shapes):
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("bits,m", [(8, 8), (8, 128), (4, 8)])
+def test_packed_matmul_compiles(one_chip, bits, m):
+    rows, dt = (K, jnp.int8) if bits == 8 else (K // 2, jnp.uint8)
+    _compile(lambda x, w, s: packed_matmul(x, w, s, bits=bits,
+                                           interpret=False),
+             one_chip, ((m, K), jnp.bfloat16), ((rows, N), dt),
+             ((K // 8, N // 128), jnp.float32))
+
+
+def test_bitplane_matmul_compiles(one_chip):
+    _compile(lambda x, p, g, m, s: bitplane_matmul(x, p, g, m, s, n_bits=8,
+                                                   interpret=False),
+             one_chip, ((8, K), jnp.bfloat16), ((8, K // 8, N), jnp.uint8),
+             ((K // 8, N), jnp.uint8), ((8, K // 8, N // 128), jnp.float32),
+             ((K // 8, N // 128), jnp.float32))
+
+
+@pytest.mark.parametrize("bits", [8, 4])
+def test_paged_attention_compiles(one_chip, bits):
+    dh_s, dt = (DH, jnp.int8) if bits == 8 else (DH // 2, jnp.uint8)
+    pool, scale = ((POOL, PAGE, KV, dh_s), dt), ((POOL, PAGE, KV),
+                                                 jnp.float32)
+    _compile(lambda q, k, ks, v, vs, t, n: paged_attention(
+                 q, k, v, ks, vs, t, n, interpret=False),
+             one_chip, ((SLOTS, KV, 1, DH), jnp.float32), pool, scale, pool,
+             scale, ((SLOTS, BLOCKS), jnp.int32), ((SLOTS,), jnp.int32))
