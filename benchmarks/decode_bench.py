@@ -1,23 +1,21 @@
-"""Decode microbenchmark: per-step latency across the compile-shape grid
-(batch x context x page-size x kv-bits) for each decode-attention backend,
-plus the fused-path footprint census.
+"""Decode checks off the chip: one decode step at each point of the
+compile-shape grid (batch x context x page-size x kv-bits) for each
+decode-attention backend, plus the fused-path footprint census.  Times
+come only from the chip benchmark (``bench/run.py``), never from here.
 
     PYTHONPATH=src python benchmarks/decode_bench.py [--quick] [--out f.json]
 
-Wall-clock on CPU (Pallas interpret mode for ``fused``) is NOT TPU time —
-the trajectory column is ``decode_step_ms`` *relative* across backends and
-shapes, and the census is the structural claim: the fused decode jaxpr
-contains neither a full-width KV gather nor an f32 KV materialization
+The census is the structural claim: the fused decode jaxpr contains
+neither a full-width KV gather nor an f32 KV materialization
 (``graph_lint`` rules ``kv-full-width-gather`` /
 ``kv-dequant-materialization``).  The CI smoke step runs ``--quick`` and
-asserts the census is clean, so a silent fallback to the gather read side
-fails fast.  Committed sweeps live in ``BENCH_decode_pr<N>.json``.
+asserts the census is clean and that the grid's steps ran, so a silent
+fallback to the gather read side or a broken shape fails fast.
 """
 from __future__ import annotations
 
 import argparse
 import json
-import time
 from typing import Dict, List
 
 import jax
@@ -28,8 +26,8 @@ from repro.analysis.graph_lint import lint_traced_fn
 from repro.launch.lint import build_engine
 
 ARCH = "phi3-mini-3.8b"
-NOTE = ("interpret-mode wall-clock is not TPU time; "
-        "bytes_per_weight is the roofline column")
+NOTE = ("decode steps that ran off the chip; times come from "
+        "bench/run.py on the chip only")
 
 
 def _state_for(eng, batch: int, context: int, page_size: int):
@@ -47,20 +45,13 @@ def _state_for(eng, batch: int, context: int, page_size: int):
     return state
 
 
-def time_decode_step(eng, batch: int, context: int, page_size: int,
-                     reps: int = 3) -> float:
-    """Mean per-step wall-clock (ms) over ``reps`` steps after one
-    compile step, re-threading the donated state like the scheduler."""
+def run_decode_step(eng, batch: int, context: int, page_size: int) -> None:
+    """One decode step at this grid point, run to completion."""
     state = _state_for(eng, batch, context, page_size)
     tok = jnp.ones((batch, 1), jnp.int32)
     index = jnp.full((batch,), context, jnp.int32)
-    logits, state = eng.decode(tok, state, index)      # compile
+    logits, state = eng.decode(tok, state, index)
     jax.block_until_ready(logits)
-    t0 = time.perf_counter()
-    for _ in range(reps):
-        logits, state = eng.decode(tok, state, index)
-    jax.block_until_ready(logits)
-    return (time.perf_counter() - t0) / reps * 1e3
 
 
 def decode_sweep(batches, contexts, page_sizes, kv_bits_list,
@@ -73,14 +64,12 @@ def decode_sweep(batches, contexts, page_sizes, kv_bits_list,
             for b in batches:
                 for ctx in contexts:
                     for page in page_sizes:
-                        ms = time_decode_step(eng, b, ctx, page)
+                        run_decode_step(eng, b, ctx, page)
                         rows.append(dict(
                             batch=b, context=ctx, page_size=page,
-                            kv_bits=kv_bits, attn_backend=ab,
-                            decode_step_ms=round(ms, 3)))
+                            kv_bits=kv_bits, attn_backend=ab))
                         print(f"  b={b} ctx={ctx} page={page} "
-                              f"kv={kv_bits} {ab}: {ms:.2f} ms",
-                              flush=True)
+                              f"kv={kv_bits} {ab}: ran", flush=True)
     return rows
 
 

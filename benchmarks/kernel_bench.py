@@ -1,17 +1,17 @@
-"""Kernel-level benchmark: HBM weight-bytes per layout + interpret-mode
-correctness timing.  Wall-clock on CPU interpret mode is NOT TPU time; the
-derived column (bytes/weight) is the roofline-relevant quantity.
+"""Kernel-level checks off the chip: HBM weight bytes per layout
+(computed) and one run of each kernel path at small shapes.  Times come
+only from the chip benchmark (``bench/run.py``), never from here.
 
     PYTHONPATH=src python benchmarks/kernel_bench.py [--quick] [--out f.json]
 
-emits one JSON object (layout bytes + kernel timings) — the CI smoke step
-runs ``--quick`` so a kernel-backend regression fails fast.
+emits one JSON object (``layout_bytes``; ``kernel_timings`` names each
+kernel path that ran) — the CI smoke step runs ``--quick`` so a
+kernel-backend regression fails fast.
 """
 from __future__ import annotations
 
 import argparse
 import json
-import time
 from typing import Dict, List
 
 import jax
@@ -67,13 +67,10 @@ def layout_bytes(k: int = 1024, n: int = 1024, pruned_frac: float = 0.5
     return rows
 
 
-def _t(f, *a):
-    f(*a)  # compile
-    t0 = time.perf_counter()
-    for _ in range(3):
-        r = f(*a)
-    jax.block_until_ready(r)
-    return (time.perf_counter() - t0) / 3 * 1e6
+def _run(kernel: str, f) -> Dict:
+    """Run ``f`` once to completion; the row names the kernel path."""
+    jax.block_until_ready(f())
+    return dict(kernel=kernel)
 
 
 def kernel_timings(m: int = 64, k: int = 512, n: int = 512) -> List[Dict]:
@@ -87,26 +84,21 @@ def kernel_timings(m: int = 64, k: int = 512, n: int = 512) -> List[Dict]:
     bp8 = to_serving_params({"w": _mixed_qt(k, n)}, 8,
                             layout="bitplane")["w"]
 
-    t = _t
     return [
-        dict(kernel="bitplane_matmul(interp)", us=round(t(
-            lambda: bwq_dense_bitplane(x, bl)), 1)),
-        dict(kernel="bitplane_serving_matmul(interp)", us=round(t(
-            lambda: qmatmul(x, bp8, backend="bitplane")), 1)),
-        dict(kernel="packed_matmul8(interp)", us=round(t(
-            lambda: bwq_dense_packed(x, pk8)), 1)),
-        dict(kernel="packed_matmul4(interp)", us=round(t(
-            lambda: bwq_dense_packed(x, pk4)), 1)),
-        dict(kernel="jnp_dense_ref", us=round(t(
-            lambda: jax.jit(lambda: x @ w)()), 1)),
+        _run("bitplane_matmul(interp)", lambda: bwq_dense_bitplane(x, bl)),
+        _run("bitplane_serving_matmul(interp)",
+             lambda: qmatmul(x, bp8, backend="bitplane")),
+        _run("packed_matmul8(interp)", lambda: bwq_dense_packed(x, pk8)),
+        _run("packed_matmul4(interp)", lambda: bwq_dense_packed(x, pk4)),
+        _run("jnp_dense_ref", lambda: x @ w),
     ]
 
 
 def paged_attention_timings(b: int = 4, kv: int = 4, g: int = 2,
                             dh: int = 64, page: int = 16,
                             nb: int = 8) -> List[Dict]:
-    """Decode attention over an int8 page pool: fused kernel (interpret)
-    vs the gather composite it replaces vs the jnp oracle."""
+    """Decode attention over an int8 page pool: the fused kernel
+    (interpret), the gather composite it replaces and the jnp oracle."""
     import jax.numpy as jnp
 
     from repro.kernels import paged_attention
@@ -144,14 +136,12 @@ def paged_attention_timings(b: int = 4, kv: int = 4, g: int = 2,
                               kv_len=kv_len)
 
     return [
-        dict(kernel="paged_attention_fused(interp)", us=round(_t(
-            lambda: paged_attention(q, kp, vp, ksc, vsc, table,
-                                    kv_len)), 1)),
-        dict(kernel="paged_attention_gather", us=round(_t(
-            gather_composite), 1)),
-        dict(kernel="paged_attention_ref", us=round(_t(
-            jax.jit(lambda: paged_attention_ref(q, kp, vp, ksc, vsc,
-                                                table, kv_len))), 1)),
+        _run("paged_attention_fused(interp)",
+             lambda: paged_attention(q, kp, vp, ksc, vsc, table, kv_len)),
+        _run("paged_attention_gather", gather_composite),
+        _run("paged_attention_ref",
+             lambda: paged_attention_ref(q, kp, vp, ksc, vsc, table,
+                                         kv_len)),
     ]
 
 
@@ -171,8 +161,8 @@ def main() -> None:
         timings = kernel_timings()
         timings += paged_attention_timings()
     result = {"layout_bytes": layouts, "kernel_timings": timings,
-              "note": "interpret-mode wall-clock is not TPU time; "
-                      "bytes_per_weight is the roofline column"}
+              "note": "kernel paths that ran off the chip; times come "
+                      "from bench/run.py on the chip only"}
     print(json.dumps(result, indent=2), flush=True)
     if args.out:
         with open(args.out, "w") as f:

@@ -3,7 +3,8 @@
 
 Covers every paper table/figure (Table II, Figs 7-13) computed from
 actually-trained quantization state, plus kernel layouts and the roofline
-aggregation from the dry-run artifacts.
+aggregation from the dry-run artifacts.  Kernel and step times come
+from the chip benchmark (``bench/run.py``) only.
 """
 from __future__ import annotations
 
@@ -28,12 +29,9 @@ def main() -> None:
     print("name,us_per_call,derived")
 
     from . import kernel_bench
-    t0 = time.time()
     for row in kernel_bench.layout_bytes():
         _emit(f"layout/{row['layout']}", 0.0,
               f"bytes_per_weight={row['bytes_per_weight']}")
-    for row in kernel_bench.kernel_timings():
-        _emit(f"kernel/{row['kernel']}", row["us"], "interpret-mode")
 
     if not args.skip_train:
         from . import paper_tables

@@ -10,12 +10,16 @@ carries the output shape).
 totals into seconds against the chip constants below; ``dominant_term``
 names the binding one.  Consumed by launch/dryrun.py and
 benchmarks/roofline.py.
+
+``hlo_op_scopes`` names the ``jax.named_scope`` each instruction of a
+compiled program ran under; a profile names its device ops by these
+instruction names.
 """
 from __future__ import annotations
 
 import dataclasses
 import re
-from typing import Dict
+from typing import Dict, Iterable
 
 # Per-chip constants (TPU-v4-class: bf16 matmul peak, HBM2e, per-chip ICI).
 PEAK_FLOPS = 275e12      # FLOP/s
@@ -147,6 +151,39 @@ def shape_census(hlo_text: str, min_bytes: int = 0) -> Dict[str, int]:
             if n >= min_bytes:
                 census[dtype] = census.get(dtype, 0) + n
     return census
+
+
+_OP_INSTR = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+) = (.*)$")
+_OP_NAME = re.compile(r'metadata=\{[^}]*?op_name="([^"]*)"')
+_OPERAND = re.compile(r"%([\w.\-]+)")
+
+
+def hlo_op_scopes(hlo_text: str, scopes: Iterable[str]) -> Dict[str, str]:
+    """``{instruction name: scope}`` from optimized HLO text printed with
+    its metadata.
+
+    An instruction's scope is the innermost of the names ``scopes`` in
+    the ``op_name`` of its metadata (the ``jax.named_scope`` path), and
+    ``unscoped`` where that path has none.  An instruction with no
+    metadata (a copy that layout assignment put in) takes the scope of
+    its first operand that has one, else ``unscoped``."""
+    scopes = frozenset(scopes)
+    out: Dict[str, str] = {}
+    for line in hlo_text.splitlines():
+        m = _OP_INSTR.match(line)
+        if m is None:
+            continue
+        name, rest = m.groups()
+        meta = _OP_NAME.search(rest)
+        if meta is not None:
+            path = [p for p in meta.group(1).split("/") if p in scopes]
+            out[name] = path[-1] if path else "unscoped"
+            continue
+        head = rest.split(", metadata=", 1)[0]
+        inherited = (out[o] for o in _OPERAND.findall(head)
+                     if out.get(o, "unscoped") != "unscoped")
+        out[name] = next(inherited, "unscoped")
+    return out
 
 
 def roofline_terms(flops: float, bytes_accessed: float,

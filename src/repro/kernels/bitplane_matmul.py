@@ -129,5 +129,6 @@ def bitplane_matmul(x, planes_packed, sign_packed, mask, scale, *,
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), jnp.float32),
         interpret=interpret,
+        name="bitplane_matmul",
     )(x, planes_packed, sign_packed, mask, scale)
     return y[:m, :n]

@@ -118,5 +118,5 @@ def packed_matmul(x, w_int, scale, *, bits: int = 8, wbr: int = 8,
         w_spec = pl.BlockSpec((block_k // 2, block_n),
                               lambda i, j, kk: (kk, j))
     y = pl.pallas_call(kern, in_specs=[x_spec, w_spec, s_spec],
-                       **common)(x, w_int, scale)
+                       name="packed_matmul", **common)(x, w_int, scale)
     return y[:m]

@@ -43,5 +43,6 @@ def pact_quant_pallas(x, beta, *, act_bits: int = 8, block_rows: int = 256,
         out_specs=pl.BlockSpec((block_rows, c), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rp, c), x.dtype),
         interpret=interpret,
+        name="pact",
     )(x, beta)
     return y[:r]

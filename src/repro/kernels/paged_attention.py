@@ -199,5 +199,5 @@ def paged_attention(q, k_pages, v_pages, k_scale, v_scale, table, kv_len,
     out = pl.pallas_call(
         kern, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, jnp.float32),
-        interpret=interpret)(*operands)
+        interpret=interpret, name="paged_attention")(*operands)
     return jnp.moveaxis(out, 1, -1).reshape(b, kv, g, dh)

@@ -374,6 +374,45 @@ def attn_forward(p: Dict, x: jnp.ndarray, positions: jnp.ndarray, *,
     With ``cache`` given, appends K/V at ``cache_index`` and attends over the
     cache (decode / incremental prefill).  ``x_kv`` enables cross-attention.
     """
+    with jax.named_scope("attn_in"):
+        q, k, v, q_pos, kv_pos = _project(
+            p, x, x_kv, positions, kv_positions, n_heads=n_heads, n_kv=n_kv,
+            d_head=d_head, rope_theta=rope_theta, mrope=mrope)
+
+    new_cache, kv_len, out = None, None, None
+    if cache is not None:
+        with jax.named_scope("kv_write"):
+            new_cache = _write_kv(cache, k, v, cache_index,
+                                  cache_bits(cache))
+    with jax.named_scope("attn_core"):
+        if cache is not None:
+            kv_len = jnp.broadcast_to(jnp.asarray(cache_index, jnp.int32)
+                                      + x.shape[1], (x.shape[0],))
+            # fused / ref decode attention reads the cache *in its stored
+            # representation* (one query token, causal self-attention
+            # only); every other shape keeps the gather read side.
+            pa = current_paged_attn_backend()
+            if pa != "gather" and x.shape[1] == 1 and x_kv is None \
+                    and causal:
+                out = _decode_read(q, new_cache, kv_len, window,
+                                   attn_softcap, pa)
+            else:
+                k, v = _gather_read(new_cache, q.dtype)
+                kv_pos = jnp.broadcast_to(jnp.arange(k.shape[1])[None, :],
+                                          (x.shape[0], k.shape[1]))
+        if out is None:
+            out = attention_core(q, k, v, q_pos, kv_pos,
+                                 causal=causal and x_kv is None,
+                                 window=window, attn_softcap=attn_softcap,
+                                 kv_len=kv_len)
+        out = out.reshape(*x.shape[:-1], n_heads * d_head)
+    with jax.named_scope("attn_out"):
+        return qmatmul(out, p["wo"]), new_cache
+
+
+def _project(p: Dict, x, x_kv, positions, kv_positions, *, n_heads: int,
+             n_kv: int, d_head: int, rope_theta: float, mrope: bool):
+    """q/k/v projections and rope; returns (q, k, v, q_pos, kv_pos)."""
     xk_src = x_kv if x_kv is not None else x
     q = qmatmul(x, p["wq"])
     k = qmatmul(xk_src, p["wk"])
@@ -390,116 +429,78 @@ def attn_forward(p: Dict, x: jnp.ndarray, positions: jnp.ndarray, *,
 
     if kv_positions is None:
         kv_positions = positions
-    if x_kv is None:  # rope only for self-attention
-        if mrope:
-            ang_q = mrope_angles(positions, d_head, rope_theta)
-            ang_k = mrope_angles(kv_positions, d_head, rope_theta)
-            q_pos = positions[..., 0]
-            kv_pos = kv_positions[..., 0]
-        else:
-            ang_q = rope_angles(positions, d_head, rope_theta)
-            ang_k = rope_angles(kv_positions, d_head, rope_theta)
-            q_pos, kv_pos = positions, kv_positions
-        q = apply_rope(q, ang_q)
-        k = apply_rope(k, ang_k)
+    if x_kv is not None:  # rope only for self-attention
+        return q, k, v, positions, kv_positions
+    if mrope:
+        ang_q = mrope_angles(positions, d_head, rope_theta)
+        ang_k = mrope_angles(kv_positions, d_head, rope_theta)
+        q_pos = positions[..., 0]
+        kv_pos = kv_positions[..., 0]
     else:
+        ang_q = rope_angles(positions, d_head, rope_theta)
+        ang_k = rope_angles(kv_positions, d_head, rope_theta)
         q_pos, kv_pos = positions, kv_positions
+    return apply_rope(q, ang_q), apply_rope(k, ang_k), v, q_pos, kv_pos
 
-    new_cache = None
-    kv_len = None
-    out = None
-    if cache is not None:
-        idx = cache_index  # (): shared fill level, or (B,): per-slot levels
-        kq, vq = k, v
-        bits = cache_bits(cache)
-        # fused / ref decode attention reads the cache *in its stored
-        # representation* (one query token, causal self-attention only);
-        # every other shape keeps the gather read side below.
-        pa = current_paged_attn_backend()
-        decode_only = (pa != "gather" and x.shape[1] == 1
-                       and x_kv is None and causal)
-        if bits < 32:
-            # quantized-at-rest cache (int8 / packed int4 with per-token/
-            # head dynamic scales): each written position is rounded exactly
-            # once; reads dequantize in-graph, so HBM traffic drops 2x/4x
-            # at ~3% metadata overhead without compounding rounding error.
-            kq, ks_sc = quantize_kv(k, bits)
-            vq, vs_sc = quantize_kv(v, bits)
-        if "table" in cache:
-            # paged: scatter the new tokens into their slots' pool pages,
-            # then gather each slot's block list back into a contiguous
-            # (B, T) view — token order matches the contiguous cache, so
-            # attention (and therefore decoding) is bit-identical.
-            table = cache["table"]
-            store = cache["pages"]
-            pids, offs = page_coords(table, idx, k.shape[1],
-                                     store["k"].shape[1])
-            new_store = dict(store,
-                             k=store["k"].at[pids, offs].set(kq),
-                             v=store["v"].at[pids, offs].set(vq))
-            if bits < 32:
-                new_store.update(
-                    k_scale=store["k_scale"].at[pids, offs].set(ks_sc),
-                    v_scale=store["v_scale"].at[pids, offs].set(vs_sc))
-            new_cache = dict(cache, pages=new_store)
-            if decode_only:
-                kv_len = jnp.broadcast_to(
-                    jnp.asarray(idx, jnp.int32) + 1, (x.shape[0],))
-                out = paged_attn(q, new_store, table, kv_len,
-                                 window=window, attn_softcap=attn_softcap,
-                                 backend=pa)
-            else:
-                ck = paged_gather(new_store["k"], table)
-                cv = paged_gather(new_store["v"], table)
-                if bits < 32:
-                    k = dequantize_kv(ck, paged_gather(new_store["k_scale"],
-                                                       table), q.dtype)
-                    v = dequantize_kv(cv, paged_gather(new_store["v_scale"],
-                                                       table), q.dtype)
-                else:
-                    k, v = ck, cv
-        else:
-            if bits < 32:
-                cks = _cache_write(cache["k_scale"], ks_sc, idx)
-                cvs = _cache_write(cache["v_scale"], vs_sc, idx)
-            ck = _cache_write(cache["k"], kq, idx)
-            cv = _cache_write(cache["v"], vq, idx)
-            new_cache = dict(cache, k=ck, v=cv)
-            if bits < 32:
-                new_cache.update(k_scale=cks, v_scale=cvs)
-            if decode_only:
-                # serve the contiguous cache through the same kernel by
-                # viewing each slot's (T, ...) row as T//page pages with
-                # an identity block table (free reshape, no trash page)
-                t = ck.shape[1]
-                page = fit_block(min(128, t), t, 1)
-                pool = {"k": _as_pool(ck, page), "v": _as_pool(cv, page)}
-                if bits < 32:
-                    pool.update(k_scale=_as_pool(cks, page),
-                                v_scale=_as_pool(cvs, page))
-                ident = jnp.arange(
-                    x.shape[0] * (t // page),
-                    dtype=jnp.int32).reshape(x.shape[0], t // page)
-                kv_len = jnp.broadcast_to(
-                    jnp.asarray(idx, jnp.int32) + 1, (x.shape[0],))
-                out = paged_attn(q, pool, ident, kv_len, window=window,
-                                 attn_softcap=attn_softcap, backend=pa)
-            elif bits < 32:
-                k = dequantize_kv(ck, cks, q.dtype)
-                v = dequantize_kv(cv, cvs, q.dtype)
-            else:
-                k, v = ck, cv
-        if out is None:
-            t = ck.shape[1]
-            kv_pos = jnp.broadcast_to(jnp.arange(t)[None, :],
-                                      (x.shape[0], t))
-            kv_len = jnp.broadcast_to(jnp.asarray(idx) + x.shape[1],
-                                      (x.shape[0],))
 
-    if out is None:
-        out = attention_core(q, k, v, q_pos, kv_pos,
-                             causal=causal and x_kv is None,
-                             window=window, attn_softcap=attn_softcap,
-                             kv_len=kv_len)
-    out = out.reshape(*x.shape[:-1], n_heads * d_head)
-    return qmatmul(out, p["wo"]), new_cache
+_KV_LEAVES = ("k", "v", "k_scale", "v_scale")
+
+
+def _write_kv(cache: Dict, k, v, idx, bits: int) -> Dict:
+    """The cache with the new K/V written at fill level ``idx``.
+
+    A quantized-at-rest cache (int8 / packed int4 with per-token/head
+    dynamic scales) rounds each written position exactly once; reads
+    dequantize in-graph, so HBM traffic drops 2x/4x at ~3% metadata
+    overhead without compounding rounding error.  A paged cache
+    scatters the new tokens into their slots' pool pages."""
+    kq, vq = k, v
+    scales = {}
+    if bits < 32:
+        kq, scales["k_scale"] = quantize_kv(k, bits)
+        vq, scales["v_scale"] = quantize_kv(v, bits)
+    new = {"k": kq, "v": vq, **scales}
+    if "table" in cache:
+        store = cache["pages"]
+        pids, offs = page_coords(cache["table"], idx, k.shape[1],
+                                 store["k"].shape[1])
+        return dict(cache, pages=dict(store, **{
+            n: store[n].at[pids, offs].set(u) for n, u in new.items()}))
+    return dict(cache, **{n: _cache_write(cache[n], u, idx)
+                          for n, u in new.items()})
+
+
+def _decode_read(q, cache: Dict, kv_len, window, attn_softcap: float,
+                 backend: str):
+    """One decode token's attention straight over the stored cache.  The
+    contiguous cache goes through the same kernel by viewing each slot's
+    (T, ...) row as T//page pages with an identity block table (a free
+    reshape, no trash page)."""
+    if "table" in cache:
+        return paged_attn(q, cache["pages"], cache["table"], kv_len,
+                          window=window, attn_softcap=attn_softcap,
+                          backend=backend)
+    b, t = cache["k"].shape[:2]
+    page = fit_block(min(128, t), t, 1)
+    pool = {n: _as_pool(cache[n], page) for n in _KV_LEAVES if n in cache}
+    ident = jnp.arange(b * (t // page), dtype=jnp.int32).reshape(
+        b, t // page)
+    return paged_attn(q, pool, ident, kv_len, window=window,
+                      attn_softcap=attn_softcap, backend=backend)
+
+
+def _gather_read(cache: Dict, dtype):
+    """Each slot's contiguous (B, T, KV, dh) K and V in ``dtype``; a paged
+    cache gathers the slot's block list back into token order, so
+    attention (and therefore decoding) is bit-identical to the contiguous
+    cache."""
+    if "table" in cache:
+        table = cache["table"]
+        store = {n: paged_gather(leaf, table)
+                 for n, leaf in cache["pages"].items()}
+    else:
+        store = cache
+    if "k_scale" in store:
+        return (dequantize_kv(store["k"], store["k_scale"], dtype),
+                dequantize_kv(store["v"], store["v_scale"], dtype))
+    return store["k"], store["v"]

@@ -26,6 +26,23 @@ from .rwkv import init_rwkv6, rwkv6_forward, rwkv6_init_state
 from .ssm import init_mamba2, mamba2_forward, mamba2_init_state
 
 
+# ``jax.named_scope`` names of the model step's parts.  Each op's HLO
+# ``op_name`` metadata carries its scope path; the innermost of these
+# names is the op's scope (``dist.hlo_analysis.hlo_op_scopes``).  Trace-time
+# metadata only: they change no fusion and cost nothing at run time.
+SCOPES = (
+    "embed",       # token (and vision) embedding
+    "kv_slice",    # one layer's cache sliced out of the stacked cache
+    "attn_in",     # pre-attention norm, activation quant, q/k/v, rope
+    "kv_write",    # quantize the new K/V and write it into the cache
+    "attn_core",   # read the cache and attend (paged kernel or gather)
+    "attn_out",    # output projection, post-norm, residual
+    "kv_update",   # the layer's cache written back into the stack
+    "mlp",         # pre-MLP norm, activation quant, MLP or experts
+    "head",        # final norm, output head, softcap
+)
+
+
 def _cdtype(cfg: ModelConfig):
     return jnp.dtype(cfg.dtype)
 
@@ -156,15 +173,17 @@ def init_lm(key, cfg: ModelConfig) -> Dict:
 
 def _index_cache(cache, i):
     """Slice layer i's cache out of stacked (L, ...) leaves."""
-    return jax.tree_util.tree_map(
-        lambda c: jax.lax.dynamic_index_in_dim(c, i, 0, keepdims=False),
-        cache)
+    with jax.named_scope("kv_slice"):
+        return jax.tree_util.tree_map(
+            lambda c: jax.lax.dynamic_index_in_dim(c, i, 0, keepdims=False),
+            cache)
 
 
 def _update_cache(cache, new_layer, i):
-    return jax.tree_util.tree_map(
-        lambda c, nl: jax.lax.dynamic_update_index_in_dim(c, nl, i, 0),
-        cache, new_layer)
+    with jax.named_scope("kv_update"):
+        return jax.tree_util.tree_map(
+            lambda c, nl: jax.lax.dynamic_update_index_in_dim(c, nl, i, 0),
+            cache, new_layer)
 
 
 # ---------------------------------------------------------------------------
@@ -174,10 +193,11 @@ def _update_cache(cache, new_layer, i):
 def _attn_block(lp, h, positions, cfg: ModelConfig, is_local,
                 cache=None, index=None):
     qc = cfg.quant
-    x = rms_norm(h, lp["ln_attn"])
-    x = act_quant(x, lp.get("beta_attn"), qc)
-    window = jnp.where(is_local, cfg.sliding_window, 0) if \
-        cfg.alt_local_global else (cfg.sliding_window or 0)
+    with jax.named_scope("attn_in"):
+        x = rms_norm(h, lp["ln_attn"])
+        x = act_quant(x, lp.get("beta_attn"), qc)
+        window = jnp.where(is_local, cfg.sliding_window, 0) if \
+            cfg.alt_local_global else (cfg.sliding_window or 0)
     # window as traced value: attention uses dynamic comparison, so pass
     # the array directly (0 disables).
     out, new_cache = attn_forward(
@@ -185,11 +205,13 @@ def _attn_block(lp, h, positions, cfg: ModelConfig, is_local,
         d_head=cfg.head_dim, rope_theta=cfg.rope_theta, causal=True,
         window=window, attn_softcap=cfg.attn_softcap, mrope=cfg.mrope,
         cache=cache, cache_index=index)
-    if cfg.use_post_norms:
-        out = rms_norm(out, lp["ln_attn_post"])
-    return h + out, new_cache
+    with jax.named_scope("attn_out"):
+        if cfg.use_post_norms:
+            out = rms_norm(out, lp["ln_attn_post"])
+        return h + out, new_cache
 
 
+@jax.named_scope("mlp")
 def _mlp_block(lp, h, cfg: ModelConfig):
     qc = cfg.quant
     x = rms_norm(h, lp["ln_mlp"])
@@ -346,8 +368,10 @@ def forward(params, cfg: ModelConfig, tokens, *, vision_embeds=None,
             positions=None, cache=None, index=None):
     """Returns (logits, aux, new_cache)."""
     mp = shard_params_tree(prepare_params(params, _cdtype(cfg)))
-    h, positions = _embed_inputs(mp, cfg, tokens, vision_embeds, positions)
-    h = constraint(h, "batch", None, None)
+    with jax.named_scope("embed"):
+        h, positions = _embed_inputs(mp, cfg, tokens, vision_embeds,
+                                     positions)
+        h = constraint(h, "batch", None, None)
     emb0 = h
     if cfg.family in ("dense", "moe", "vlm"):
         h, aux, new_cache = _walk_dense(mp, cfg, h, positions, cache, index)
@@ -358,11 +382,12 @@ def forward(params, cfg: ModelConfig, tokens, *, vision_embeds=None,
                                          index)
     else:
         raise ValueError(cfg.family)
-    h = rms_norm(h, mp["final_norm"])
-    head = mp["lm_head"] if "lm_head" in mp else mp["embed"].T
-    logits = qmatmul(h, head).astype(jnp.float32)
-    logits = softcap(logits, cfg.logit_softcap)
-    logits = constraint(logits, "batch", None, "vocab")
+    with jax.named_scope("head"):
+        h = rms_norm(h, mp["final_norm"])
+        head = mp["lm_head"] if "lm_head" in mp else mp["embed"].T
+        logits = qmatmul(h, head).astype(jnp.float32)
+        logits = softcap(logits, cfg.logit_softcap)
+        logits = constraint(logits, "batch", None, "vocab")
     return logits, aux, new_cache
 
 

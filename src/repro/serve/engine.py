@@ -187,15 +187,16 @@ class ServeEngine:
             self.api = ModelAPI(cfg)
         self.mesh = get_mesh()
         self._pad_shapes = None   # true leaf shapes when params pad-placed
-        self._prefill_j = self._jit(self.api.prefill,
+        self._prefill_j = self._jit(self.api.prefill, "prefill",
                                     static_argnames=("extra_slots",))
-        self._prefill_at_j = self._jit(self.api.prefill_at)
-        self._prefill_chunk_j = self._jit(self.api.prefill_chunk_at)
+        self._prefill_at_j = self._jit(self.api.prefill_at, "prefill_at")
+        self._prefill_chunk_j = self._jit(self.api.prefill_chunk_at,
+                                          "prefill_chunk_at")
         # decode_step(params, tokens, state, index): the state (arg 2) is
         # consumed and rebuilt every step — donate it so the cache updates
         # in place instead of double-buffering (graph lint enforces this)
         self._decode_j = self._jit(
-            self.api.decode_step,
+            self.api.decode_step, "decode_step",
             **({"donate_argnums": (2,)} if self.donate_state else {}))
         self.draft_params = None
         self._verify_j = None
@@ -215,7 +216,7 @@ class ServeEngine:
             self.draft_params = make_draft_params(self.params,
                                                   self.speculate_planes)
             self._verify_j = self._jit(
-                self.api.verify_step,
+                self.api.verify_step, "verify_step",
                 **({"donate_argnums": (2,)} if self.donate_state else {}))
         if self.mesh is not None:
             self.params = self._place_params(self.params)
@@ -235,11 +236,12 @@ class ServeEngine:
                        self.params,
                        is_leaf=lambda x: isinstance(x, deployed)))
 
-    def _jit(self, fn, **jit_kwargs):
+    def _jit(self, fn, name: str, **jit_kwargs):
         """jit ``fn`` with the engine's matmul + decode-attention backends
         active at trace time — both are part of the traced program, and
         each engine owns its jit cache, so traces never leak across
-        backends."""
+        backends.  ``name`` names the program: its XLA module is
+        ``jit_<name>``, which is how a profile finds it."""
         backend, attn = self.backend, self.attn_backend
 
         @functools.wraps(fn)
@@ -247,6 +249,7 @@ class ServeEngine:
             params = self._unpad_params(params)
             with matmul_backend(backend), paged_attn_backend(attn):
                 return fn(params, *args, **kwargs)
+        run.__name__ = run.__qualname__ = name
         return jax.jit(run, **jit_kwargs)
 
     # ---- sharding helpers -----------------------------------------------

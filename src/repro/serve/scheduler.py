@@ -75,6 +75,18 @@ to the baseline paths):
 Time is measured in scheduler *ticks* (one decode step per tick), which
 keeps admission order deterministic and lets tests/benchmarks replay
 staggered arrival traces exactly.
+
+Every tick emits host spans (``jax.profiler.TraceAnnotation``, named in
+:data:`SPANS`) that record only while a profiler session is active and
+cost about a microsecond each otherwise; nothing turns them on or off.
+Their keyword args are host integers the scheduler already holds, so a
+span never waits on the device.  Spans around a blocking read of the
+device's results end in ``_wait``.  A dispatch (``sched.prefill_chunk``,
+``sched.decode``) can block too: where the device has no room for the
+program's outputs, the runtime holds the call until an earlier program
+ends and frees its inputs (a profile shows ``AllocateBufferAwait``).  So
+the host time a tick costs is read as the device's idle time inside
+``sched.tick``, not from span durations.
 """
 from __future__ import annotations
 
@@ -88,6 +100,22 @@ import jax.numpy as jnp
 import numpy as np
 
 from .sampling import GenerationResult, Request, sample_token
+
+_span = jax.profiler.TraceAnnotation
+
+# Host spans of one tick, in the order a tick opens them.  All nest in
+# ``sched.tick``; args in brackets.
+SPANS = (
+    "sched.tick",              # [tick, live, queued, chunks] one step()
+    "sched.admit",             # [admitted, queued] fill free slots
+    "sched.prefill_chunk",     # [uid, slot, start, width, real] dispatch
+    "sched.first_token_wait",  # [uid] blocking sample after a prompt
+    "sched.grow_pages",        # [pages_in_use] decode page growth
+    "sched.set_tables",        # push dirty block tables to the device
+    "sched.decode",            # [rows, live, fill_sum, fill_max] dispatch
+    "sched.logits_wait",       # the decode logits' host transfer
+    "sched.sample",            # host argmax / sampling and retirement
+)
 
 
 @dataclasses.dataclass
@@ -438,13 +466,15 @@ class Scheduler:
     def _first_token(self, slot: _Slot, logits_row) -> None:
         sp = slot.req.sampling
         key = jax.random.fold_in(slot.key, 0) if sp.temperature > 0 else None
-        tok = int(sample_token(logits_row, sp, key))
+        with _span("sched.first_token_wait", uid=slot.req.uid):
+            tok = int(sample_token(logits_row, sp, key))
         slot.generated.append(tok)
         slot.last_tok = tok
 
     def _flush_tables(self) -> None:
         if self._tables_dirty:
-            self.state = self.engine.set_tables(self.state, self.tables)
+            with _span("sched.set_tables"):
+                self.state = self.engine.set_tables(self.state, self.tables)
             self._tables_dirty = False
 
     def _plan_chunks(self, req: Request, skip: int = 0) -> List[tuple]:
@@ -666,19 +696,21 @@ class Scheduler:
         self.sched_stats["resumes"] += 1
         return True
 
-    def _admit(self) -> None:
-        """Fill free slots from the priority/arrival-ordered queue.
+    def _admit(self) -> int:
+        """Fill free slots from the priority/arrival-ordered queue;
+        returns how many requests entered a slot.
 
         Requests the pool cannot host yet are *skipped over* — a blocked
         oversized (or parked) request must not head-of-line stall
         admissible ones behind it; it stays queued at its priority rank
         and is retried every tick."""
         free = [i for i, s in enumerate(self.slots) if s is None]
+        admitted = 0
         if not free or not self.waiting:
-            return
+            return admitted
         for entry in list(self.waiting):
             if not free:
-                return
+                break
             if _entry_req(entry).arrival > self.tick:
                 continue
             if isinstance(entry, _Parked):
@@ -688,23 +720,42 @@ class Scheduler:
             if ok:
                 free.pop(0)
                 self.waiting.remove(entry)
+                admitted += 1
+        return admitted
 
     # ---- chunked / paged prompt insertion --------------------------------
-    def _advance_prefills(self) -> None:
+    def _advance_prefills(self) -> int:
         """One prompt chunk per mid-prefill slot per tick; the final chunk
         samples the request's first token (as monolithic admission does)
-        and publishes the prompt's full pages to the prefix cache."""
+        and publishes the prompt's full pages to the prefix cache.
+        Returns the number of chunks run."""
+        n = 0
         for i, s in enumerate(self.slots):
             if s is None or not s.chunks:
                 continue
             self._flush_tables()
             batch, start, col = s.chunks.pop(0)
-            logits, self.state = self.engine.prefill_chunk_at(
-                batch, self.state, i, start)
+            width = batch["tokens"].shape[1]
+            with _span("sched.prefill_chunk", uid=s.req.uid, slot=i,
+                       start=start, width=width,
+                       real=self._chunk_tokens(s.req, start, width)):
+                logits, self.state = self.engine.prefill_chunk_at(
+                    batch, self.state, i, start)
+            n += 1
             if not s.chunks:
                 self._register_prompt_pages(i)
                 self._first_token(s, logits[0, -1 if col is None else col])
                 self._maybe_retire(i)
+        return n
+
+    def _chunk_tokens(self, req: Request, start: int, width: int) -> int:
+        """Prompt tokens in the chunk of ``width`` tokens at cache
+        position ``start`` (the rest is padding); a VLM's later chunks
+        start past its vision prefix."""
+        cfg = self.engine.api.cfg
+        tv = cfg.vision_tokens if cfg.family == "vlm" else 0
+        lo = max(0, start - tv)
+        return max(0, min(width, req.inputs["tokens"].shape[1] - lo))
 
     # ---- preemption ------------------------------------------------------
     def _pick_victim(self, exclude: int) -> Optional[int]:
@@ -897,32 +948,50 @@ class Scheduler:
     def step(self) -> None:
         """Admit what has arrived, advance mid-prefill slots one chunk,
         then advance every decoding slot one token."""
-        self._admit()
-        if self._insert_path:
-            self._advance_prefills()
-        live = [i for i, s in enumerate(self.slots)
-                if s is not None and not s.chunks]
+        with _span("sched.tick", tick=self.tick) as tick_span:
+            with _span("sched.admit") as admit_span:
+                admitted = self._admit()
+                admit_span.set_metadata(admitted=admitted,
+                                        queued=len(self.waiting))
+            chunks = self._advance_prefills() if self._insert_path else 0
+            live = [i for i, s in enumerate(self.slots)
+                    if s is not None and not s.chunks]
+            tick_span.set_metadata(live=len(live), queued=len(self.waiting),
+                                   chunks=chunks)
+            self._decode_tick(live)
+        self.tick += 1
+
+    def _decode_tick(self, live: List[int]) -> None:
+        """Advance the slots in ``live`` one token (or one speculative
+        round)."""
         if live and self.engine.speculate_planes and \
                 all(self.slots[i].req.sampling.temperature == 0
                     for i in live):
             if self._spec_tick(live):
-                self.tick += 1
                 return
         if live and self.paged:
-            live = self._grow_pages(live)
-        if live:
-            self._flush_tables()
-            toks = np.zeros((self.n_slots, 1), np.int32)
-            # parked rows write their (ignored) K/V at the last position —
-            # with a paged cache that position routes to the trash page —
-            # where it stays masked by the row's fill level
-            idx = np.full((self.n_slots,), self.total_len - 1, np.int32)
-            for i in live:
-                toks[i, 0] = self.slots[i].last_tok
-                idx[i] = self.slots[i].index
+            with _span("sched.grow_pages") as grow_span:
+                live = self._grow_pages(live)
+                grow_span.set_metadata(pages_in_use=self.allocator.in_use)
+        if not live:
+            return
+        self._flush_tables()
+        toks = np.zeros((self.n_slots, 1), np.int32)
+        # parked rows write their (ignored) K/V at the last position —
+        # with a paged cache that position routes to the trash page —
+        # where it stays masked by the row's fill level
+        idx = np.full((self.n_slots,), self.total_len - 1, np.int32)
+        for i in live:
+            toks[i, 0] = self.slots[i].last_tok
+            idx[i] = self.slots[i].index
+        fills = [int(idx[i]) + 1 for i in live]
+        with _span("sched.decode", rows=self.n_slots, live=len(live),
+                   fill_sum=sum(fills), fill_max=max(fills)):
             logits, self.state = self.engine.decode(
                 jnp.asarray(toks), self.state, jnp.asarray(idx))
+        with _span("sched.logits_wait"):
             lg = np.asarray(logits)       # one host transfer per tick
+        with _span("sched.sample"):
             for i in live:
                 slot = self.slots[i]
                 sp = slot.req.sampling
@@ -935,7 +1004,6 @@ class Scheduler:
                 slot.last_tok = tok
                 slot.index += 1
                 self._maybe_retire(i)
-        self.tick += 1
 
     # ---- reporting -------------------------------------------------------
     def compile_footprint(self, prompt_widths=None) -> List[Any]:

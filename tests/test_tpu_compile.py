@@ -8,13 +8,15 @@ worker collects the same tests and only the one running them loads the
 TPU library.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.kernels import bitplane_matmul, packed_matmul, paged_attention
+from repro.kernels import (bitplane_matmul, packed_matmul, paged_attention,
+                           pact_quant_pallas)
 
 K, N = 3072, 8192                        # d_model -> d_ff
 KV, DH, PAGE, SLOTS, BLOCKS = 32, 96, 16, 4, 68
@@ -37,10 +39,16 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
-def _compile(fn, one_chip, *shapes):
+def _compile(fn, kernel, one_chip, *shapes):
+    """Compile ``fn`` and check that its Pallas call runs as a Mosaic
+    kernel whose instruction carries the kernel's own name (a profile
+    names the device op after it, whatever wraps the call)."""
     args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
     text = jax.jit(fn).lower(*args).compile().as_text()
-    assert "tpu_custom_call" in text
+    calls = re.findall(
+        r'%([\w.\-]+) = .*custom_call_target="tpu_custom_call"', text)
+    assert calls, "no Mosaic kernel in the compiled program"
+    assert all(re.fullmatch(rf"{kernel}(\.\d+)?", c) for c in calls), calls
 
 
 @pytest.mark.parametrize("bits,m", [(8, 8), (8, 128), (4, 8)])
@@ -48,14 +56,14 @@ def test_packed_matmul_compiles(one_chip, bits, m):
     rows, dt = (K, jnp.int8) if bits == 8 else (K // 2, jnp.uint8)
     _compile(lambda x, w, s: packed_matmul(x, w, s, bits=bits,
                                            interpret=False),
-             one_chip, ((m, K), jnp.bfloat16), ((rows, N), dt),
+             "packed_matmul", one_chip, ((m, K), jnp.bfloat16), ((rows, N), dt),
              ((K // 8, N // 128), jnp.float32))
 
 
 def test_bitplane_matmul_compiles(one_chip):
     _compile(lambda x, p, g, m, s: bitplane_matmul(x, p, g, m, s, n_bits=8,
                                                    interpret=False),
-             one_chip, ((8, K), jnp.bfloat16), ((8, K // 8, N), jnp.uint8),
+             "bitplane_matmul", one_chip, ((8, K), jnp.bfloat16), ((8, K // 8, N), jnp.uint8),
              ((K // 8, N), jnp.uint8), ((8, K // 8, N // 128), jnp.float32),
              ((K // 8, N // 128), jnp.float32))
 
@@ -67,5 +75,10 @@ def test_paged_attention_compiles(one_chip, bits):
                                                  jnp.float32)
     _compile(lambda q, k, ks, v, vs, t, n: paged_attention(
                  q, k, v, ks, vs, t, n, interpret=False),
-             one_chip, ((SLOTS, KV, 1, DH), jnp.float32), pool, scale, pool,
+             "paged_attention", one_chip, ((SLOTS, KV, 1, DH), jnp.float32), pool, scale, pool,
              scale, ((SLOTS, BLOCKS), jnp.int32), ((SLOTS,), jnp.int32))
+
+
+def test_pact_compiles(one_chip):
+    _compile(lambda x, b: pact_quant_pallas(x, b, interpret=False), "pact",
+             one_chip, ((16, K), jnp.float32), ((1,), jnp.float32))
