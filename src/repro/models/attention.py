@@ -178,7 +178,7 @@ def current_paged_attn_backend() -> str:
 
 
 def paged_attn(q, store: Dict, table, kv_len, *, window=0,
-               attn_softcap: float = 0.0,
+               attn_softcap: float = 0.0, trash_page: bool = True,
                backend: Optional[str] = None) -> jnp.ndarray:
     """One decode step of attention straight over a (quantized) page pool.
 
@@ -187,7 +187,9 @@ def paged_attn(q, store: Dict, table, kv_len, *, window=0,
     ``table``: (B, nb); ``kv_len``: (B,) fill levels *including* the
     token just written.  Returns (B, 1, H, dh) in q's dtype.  The
     contiguous cache is served through the same entry by viewing each
-    slot's (T, ...) row as pages (see ``attn_forward``)."""
+    slot's (T, ...) row as pages with ``trash_page=False``: its page 0
+    holds data, so the fused kernel bounds a row by ``kv_len`` alone
+    (see ``_decode_read``)."""
     backend = backend or current_paged_attn_backend()
     b, s, h, dh = q.shape
     kv = store["k"].shape[2]
@@ -197,7 +199,7 @@ def paged_attn(q, store: Dict, table, kv_len, *, window=0,
     args = (qg, store["k"], store["v"], store.get("k_scale"),
             store.get("v_scale"), table, kv_len)
     if backend == "fused":
-        out = _fused_over_heads(args, win, attn_softcap)
+        out = _fused_over_heads(args, win, attn_softcap, trash_page)
     elif backend == "ref":
         out = paged_attention_ref(*args, window=win, softcap=attn_softcap)
     else:
@@ -206,7 +208,7 @@ def paged_attn(q, store: Dict, table, kv_len, *, window=0,
     return out.reshape(b, s, h, dh).astype(q.dtype)
 
 
-def _fused_over_heads(args, win, attn_softcap):
+def _fused_over_heads(args, win, attn_softcap, trash_page):
     """The paged-attention kernel, each device taking its share of the KV
     heads when the mesh's 'model' axis divides them (the decode state's
     layout, ``cache_pspecs``); otherwise every device takes all heads.
@@ -235,7 +237,7 @@ def _fused_over_heads(args, win, attn_softcap):
             else (None, None, rest)
         return paged_attention(q, k, v, ks, vs, rest[0], rest[1],
                                window=rest[2] if len(rest) > 2 else None,
-                               softcap=attn_softcap)
+                               softcap=attn_softcap, trash_page=trash_page)
     return run_kernel(kern, *ops, in_specs=specs, out_specs=P(None, heads))
 
 
@@ -486,7 +488,8 @@ def _decode_read(q, cache: Dict, kv_len, window, attn_softcap: float,
     ident = jnp.arange(b * (t // page), dtype=jnp.int32).reshape(
         b, t // page)
     return paged_attn(q, pool, ident, kv_len, window=window,
-                      attn_softcap=attn_softcap, backend=backend)
+                      attn_softcap=attn_softcap, trash_page=False,
+                      backend=backend)
 
 
 def _gather_read(cache: Dict, dtype):

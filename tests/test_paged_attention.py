@@ -5,6 +5,8 @@
   levels — previously covered only indirectly through serving parity;
 * kernel vs ``paged_attention_ref`` allclose across kv-bits, GQA ratios,
   ragged fill levels, sliding windows, softcap, and ``block_kv`` tiles;
+  fills at and around page and multi-page step boundaries, a parked row
+  (an all-trash table), and the contiguous cache's identity view;
 * graph-lint footprint census: the fused decode jaxpr holds neither a
   full-width KV gather nor an f32 KV materialization (``kv-clean``), and
   a forced gather fallback under a fused engine is an ERROR;
@@ -21,9 +23,9 @@ from repro.configs import REGISTRY
 from repro.kernels.paged_attention import paged_attention
 from repro.kernels.ref import paged_attention_ref
 from repro.models.api import build
-from repro.models.attention import (PAGED_ATTN_BACKENDS, page_coords,
-                                    paged_attn_backend, paged_gather,
-                                    quantize_kv)
+from repro.models.attention import (PAGED_ATTN_BACKENDS, _decode_read,
+                                    page_coords, paged_attn_backend,
+                                    paged_gather, quantize_kv)
 from repro.models.common import QuantConfig
 from repro.serve import Request, SamplingParams, ServeEngine
 
@@ -108,22 +110,110 @@ def _pool_case(key, b, kv, g, dh, page, nb, bits):
     return q, kq, vq, ksc, vsc, table, kv_len
 
 
-@pytest.mark.parametrize("bits,g,window,softcap,block_kv", [
-    (8, 1, None, 0.0, 1),
-    (8, 4, None, 0.0, 2),          # GQA grouping + kv-head tiling
-    (4, 2, None, 0.0, 1),          # nibble-packed int4 in-kernel unpack
-    (32, 2, None, 0.0, 1),         # float pool (paged, unquantized)
-    (8, 2, 5, 30.0, 1),            # sliding window + softcap
+# page 16 and 20 blocks: the kernel copies 8 pages (128 tokens) a step.
+# Fills of 1 token, one page, one block, one block + 1, two blocks and
+# the whole table; 8 and 16 live pages are whole steps, 9 and 20 are not.
+WALK_PAGE, WALK_NB = 16, 20
+WALK = (1, 16, 128, 129, 256, 320)
+CONTIG_T = 256                # the contiguous view: 2 pages of 128 a row
+CONTIG = (1, 128, 129, 256)
+
+
+def _walk_case(key, bits, g, fills, *, kv=4, dh=16, page=WALK_PAGE,
+               nb=WALK_NB, parked=False):
+    """A page pool whose rows hold ``fills`` tokens in shuffled pages,
+    the rest of each table pointing at the trash page; ``parked`` adds a
+    last row as the scheduler sends a parked slot (an all-trash table,
+    ``kv_len = nb * page``)."""
+    n_live = [-(-f // page) for f in fills]
+    n_pages = 1 + sum(n_live)
+    b = len(fills) + parked
+    ks = jax.random.split(key, 4)
+    q = jax.random.normal(ks[0], (b, kv, g, dh), jnp.float32)
+    kf = jax.random.normal(ks[1], (n_pages, page, kv, dh), jnp.float32)
+    vf = jax.random.normal(ks[2], (n_pages, page, kv, dh), jnp.float32)
+    if bits < 32:
+        kq, ksc = quantize_kv(kf, bits)
+        vq, vsc = quantize_kv(vf, bits)
+    else:
+        kq, vq, ksc, vsc = kf, vf, None, None
+    ids = 1 + np.asarray(jax.random.permutation(ks[3], n_pages - 1))
+    table = np.zeros((b, nb), np.int32)
+    starts = np.cumsum([0] + n_live)
+    for r, n in enumerate(n_live):
+        table[r, :n] = ids[starts[r]:starts[r] + n]
+    kv_len = np.asarray(list(fills) + [nb * page] * parked, np.int32)
+    return q, kq, vq, ksc, vsc, jnp.asarray(table), jnp.asarray(kv_len)
+
+
+def _contiguous_read(bits, g, fills, window, softcap, *, kv=4, dh=16):
+    """Fused and reference decode reads of a contiguous cache through
+    ``_decode_read``'s page-128 view (identity table, page 0 is data)."""
+    b = len(fills)
+    ks = jax.random.split(jax.random.PRNGKey(bits + g), 3)
+    q = jax.random.normal(ks[0], (b, 1, kv * g, dh), jnp.float32)
+    cache = {}
+    for name, k in (("k", ks[1]), ("v", ks[2])):
+        x = jax.random.normal(k, (b, CONTIG_T, kv, dh), jnp.float32)
+        if bits < 32:
+            cache[name], cache[name + "_scale"] = quantize_kv(x, bits)
+        else:
+            cache[name] = x
+    kv_len = jnp.asarray(fills, jnp.int32)
+    win = 0 if window is None else window
+    return [_decode_read(q, cache, kv_len, win, softcap, backend)
+            for backend in ("fused", "ref")]
+
+
+@pytest.mark.parametrize("bits,g,window,softcap,block_kv,fills,layout", [
+    pytest.param(8, 1, None, 0.0, 1, None, "paged", id="8-1-None-0.0-1"),
+    # GQA grouping + kv-head tiling
+    pytest.param(8, 4, None, 0.0, 2, None, "paged", id="8-4-None-0.0-2"),
+    # nibble-packed int4 in-kernel unpack
+    pytest.param(4, 2, None, 0.0, 1, None, "paged", id="4-2-None-0.0-1"),
+    # float pool (paged, unquantized)
+    pytest.param(32, 2, None, 0.0, 1, None, "paged", id="32-2-None-0.0-1"),
+    # sliding window + softcap
+    pytest.param(8, 2, 5, 30.0, 1, None, "paged", id="8-2-5-30.0-1"),
+    # rows of every fill in one call: the per-row walk bound
+    *[pytest.param(bits, g, None, 0.0, None, WALK, "paged",
+                   id=f"walk-{name}-g{g}")
+      for bits, name in ((8, "int8"), (4, "int4"), (32, "f32"))
+      for g in (1, 2)],
+    pytest.param(8, 1, 40, 30.0, None, WALK, "paged",
+                 id="walk-window-softcap-g1"),
+    pytest.param(8, 2, 40, 30.0, None, WALK, "paged",
+                 id="walk-window-softcap-g2"),
+    # a parked row among them: walks nothing, returns zeros
+    pytest.param(8, 1, None, 0.0, None, WALK, "parked", id="parked-g1"),
+    pytest.param(4, 2, None, 0.0, None, WALK, "parked", id="parked-g2"),
+    pytest.param(8, 1, None, 0.0, None, (), "parked", id="parked-only"),
+    # the contiguous cache's identity view, page 0 holding data
+    pytest.param(8, 1, None, 0.0, None, CONTIG, "contiguous",
+                 id="contiguous-int8-g1"),
+    pytest.param(4, 2, 100, 30.0, None, CONTIG, "contiguous",
+                 id="contiguous-int4-g2-window-softcap"),
 ])
-def test_kernel_matches_ref(bits, g, window, softcap, block_kv):
-    b, kv, dh, page, nb = 2, 4, 16, 4, 3
-    case = _pool_case(jax.random.PRNGKey(bits * 7 + g), b, kv, g, dh,
-                      page, nb, bits)
-    got = paged_attention(*case, window=window, softcap=softcap,
-                          block_kv=block_kv)
-    want = paged_attention_ref(*case, window=window, softcap=softcap)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               atol=1e-5, rtol=1e-5)
+def test_kernel_matches_ref(bits, g, window, softcap, block_kv, fills,
+                            layout):
+    if layout == "contiguous":
+        got, want = _contiguous_read(bits, g, fills, window, softcap)
+    else:
+        key = jax.random.PRNGKey(bits * 7 + g)
+        case = (_pool_case(key, 2, 4, g, 16, 4, 3, bits) if fills is None
+                else _walk_case(key, bits, g, fills,
+                                parked=layout == "parked"))
+        got = paged_attention(*case, window=window, softcap=softcap,
+                              block_kv=block_kv)
+        want = paged_attention_ref(*case, window=window, softcap=softcap)
+    got, want = np.asarray(got), np.asarray(want)
+    if layout == "parked":
+        # the reference walks the trash page for the parked row; the
+        # kernel walks nothing there (zeros), and the row is discarded
+        assert np.isfinite(got[-1]).all()
+        np.testing.assert_array_equal(got[-1], 0.0)
+        got, want = got[:-1], want[:-1]
+    np.testing.assert_allclose(got, want, atol=1e-5, rtol=1e-5)
 
 
 def test_kernel_trash_page_stays_inert():

@@ -21,6 +21,9 @@ from repro.kernels import (bitplane_matmul, packed_matmul, paged_attention,
 K, N = 3072, 8192                        # d_model -> d_ff
 KV, DH, PAGE, SLOTS, BLOCKS = 32, 96, 16, 4, 68
 POOL = 1 + SLOTS * BLOCKS                # trash page + worst case
+# bench/cells/phi3-chat.json: 16 slots, max_len 2048 (128 blocks of 16),
+# the 1281-page pool the cell builds
+CHAT_SLOTS, CHAT_BLOCKS, CHAT_POOL = 16, 128, 1281
 
 
 @pytest.fixture(scope="module")
@@ -68,15 +71,18 @@ def test_bitplane_matmul_compiles(one_chip):
              ((K // 8, N // 128), jnp.float32))
 
 
+@pytest.mark.parametrize("slots,blocks,n_pages", [
+    (SLOTS, BLOCKS, POOL), (CHAT_SLOTS, CHAT_BLOCKS, CHAT_POOL)])
 @pytest.mark.parametrize("bits", [8, 4])
-def test_paged_attention_compiles(one_chip, bits):
+def test_paged_attention_compiles(one_chip, bits, slots, blocks, n_pages):
     dh_s, dt = (DH, jnp.int8) if bits == 8 else (DH // 2, jnp.uint8)
-    pool, scale = ((POOL, PAGE, KV, dh_s), dt), ((POOL, PAGE, KV),
-                                                 jnp.float32)
+    pool, scale = ((n_pages, PAGE, KV, dh_s), dt), ((n_pages, PAGE, KV),
+                                                    jnp.float32)
     _compile(lambda q, k, ks, v, vs, t, n: paged_attention(
                  q, k, v, ks, vs, t, n, interpret=False),
-             "paged_attention", one_chip, ((SLOTS, KV, 1, DH), jnp.float32), pool, scale, pool,
-             scale, ((SLOTS, BLOCKS), jnp.int32), ((SLOTS,), jnp.int32))
+             "paged_attention", one_chip, ((slots, KV, 1, DH), jnp.float32),
+             pool, scale, pool, scale, ((slots, blocks), jnp.int32),
+             ((slots,), jnp.int32))
 
 
 def test_pact_compiles(one_chip):
