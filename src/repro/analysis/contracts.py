@@ -340,7 +340,8 @@ def _walk_paged(cache, path, findings: List[Finding],
             c.err("PC1", f"table stack dim {tshape[0]} != pool stack dim "
                          f"{first[0]}", "['table']")
         quantized = any(_dtype(v) in ("int8", "uint8")
-                        for k, v in pages.items() if k in ("k", "v"))
+                        for k, v in pages.items()
+                        if k in ("k", "v", "ckv", "kpe"))
         if quantized and not any(k.endswith("_scale") for k in pages):
             c.err("PC3", "quantized page pool is missing its per-token "
                          "scale leaves", "['pages']")

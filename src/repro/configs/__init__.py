@@ -5,6 +5,7 @@ from .llama4_scout_17b_a16e import CONFIG as llama4_scout_17b_a16e
 from .phi3_mini_3_8b import CONFIG as phi3_mini_3_8b
 from .starcoder2_15b import CONFIG as starcoder2_15b
 from .deepseek_7b import CONFIG as deepseek_7b
+from .deepseek_v2_lite import CONFIG as deepseek_v2_lite
 from .gemma2_27b import CONFIG as gemma2_27b
 from .zamba2_1_2b import CONFIG as zamba2_1_2b
 from .rwkv6_1_6b import CONFIG as rwkv6_1_6b
@@ -14,7 +15,7 @@ from .seamless_m4t_large_v2 import CONFIG as seamless_m4t_large_v2
 REGISTRY = {c.name: c for c in [
     granite_moe_3b_a800m, llama4_scout_17b_a16e, phi3_mini_3_8b,
     starcoder2_15b, deepseek_7b, gemma2_27b, zamba2_1_2b, rwkv6_1_6b,
-    qwen2_vl_2b, seamless_m4t_large_v2,
+    qwen2_vl_2b, seamless_m4t_large_v2, deepseek_v2_lite,
 ]}
 
 

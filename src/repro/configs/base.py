@@ -2,9 +2,10 @@
 from __future__ import annotations
 
 import dataclasses
-from typing import Tuple
+from typing import Optional, Tuple
 
 from ..models.common import QuantConfig
+from ..models.rope import YarnScaling
 
 
 @dataclasses.dataclass(frozen=True)
@@ -22,6 +23,17 @@ class ModelConfig:
     n_experts: int = 0
     top_k: int = 0
     n_shared_experts: int = 0
+    n_held_experts: int = 0        # experts held here (0 => all n_experts)
+    expert_offset: int = 0         # first held expert's id
+    norm_topk_prob: bool = True    # renormalize the top-k gate values
+    first_dense_layers: int = 0    # leading layers with a dense MLP
+    dense_d_ff: int = 0            # their MLP width
+    # multi-head latent attention (MLA): kv_lora_rank > 0 selects it
+    kv_lora_rank: int = 0
+    qk_nope_dim: int = 0
+    qk_rope_dim: int = 0
+    v_head_dim: int = 0
+    yarn: Optional[YarnScaling] = None   # yarn-scaled RoPE (MLA layers)
     # SSM / hybrid
     ssm_state: int = 0
     ssm_expand: int = 2
@@ -59,6 +71,10 @@ class ModelConfig:
     def head_dim(self) -> int:
         return self.d_head or self.d_model // self.n_heads
 
+    @property
+    def held_experts(self) -> int:
+        return self.n_held_experts or self.n_experts
+
     def with_quant(self, qc: QuantConfig) -> "ModelConfig":
         return dataclasses.replace(self, quant=qc)
 
@@ -84,6 +100,14 @@ class ModelConfig:
             if self.vision_tokens else 0,
             remat=False,
         )
+        if self.kv_lora_rank:
+            base.update(kv_lora_rank=64, qk_nope_dim=32, qk_rope_dim=16,
+                        v_head_dim=32, d_head=48)
+        if self.first_dense_layers:
+            base.update(n_layers=3, dense_d_ff=384)
+        if self.n_held_experts:
+            base.update(n_held_experts=max(
+                1, base["n_experts"] * self.n_held_experts // self.n_experts))
         base.update(over)
         return dataclasses.replace(self, **base)
 

@@ -150,6 +150,29 @@ def paged_attention_ref(q, k_pages, v_pages, k_scale, v_scale, table,
     return jnp.einsum("bkgt,btkd->bkgd", p, v)
 
 
+def latent_attention_ref(q_lat, q_pe, ckv, ckv_scale, kpe, kpe_scale,
+                         table, kv_len, *, sm_scale: float) -> jnp.ndarray:
+    """Gather-then-softmax oracle for ``kernels.latent_attention``: the
+    same contract, materializing each row's dequantized (B, T, r) latent
+    and (B, T, dr) rope keys."""
+    b = q_lat.shape[0]
+
+    def gather(leaf):
+        x = jnp.take(leaf, table, axis=0)
+        return x.reshape(b, -1, *leaf.shape[2:]).astype(jnp.float32)
+
+    c = gather(ckv) * gather(ckv_scale)[..., None]
+    pe = gather(kpe) * gather(kpe_scale)[..., None]
+    s = (jnp.einsum("bhr,btr->bht", q_lat.astype(jnp.float32), c,
+                    preferred_element_type=jnp.float32)
+         + jnp.einsum("bhd,btd->bht", q_pe.astype(jnp.float32), pe,
+                      preferred_element_type=jnp.float32)) * sm_scale
+    pos = jnp.arange(c.shape[1], dtype=jnp.int32)[None, None, :]
+    s = jnp.where(pos < jnp.asarray(kv_len, jnp.int32)[:, None, None], s,
+                  -2.0e38)
+    return jnp.einsum("bht,btr->bhr", jax.nn.softmax(s, axis=-1), c)
+
+
 def pact_quant_ref(x, beta, act_bits: int) -> jnp.ndarray:
     """Symmetric PACT clip + uniform quantization (forward only)."""
     levels = float(2 ** (act_bits - 1) - 1)

@@ -15,6 +15,7 @@ import jax.numpy as jnp
 
 from ..configs.base import ModelConfig, ShapeCell
 from . import encdec, transformer
+from .attention import is_kv_node
 
 
 def _sds(shape, dtype):
@@ -52,7 +53,7 @@ def _row_cache_view(cache, slot, fresh=None):
         if "table" in cache:
             return dict(cache, table=jax.lax.dynamic_slice_in_dim(
                 cache["table"], slot, 1, axis=1))
-        if "k" in cache and "v" in cache:
+        if is_kv_node(cache):
             return {k: jax.lax.dynamic_slice_in_dim(v, slot, 1, axis=1)
                     for k, v in cache.items()}
         return {k: _row_cache_view(v, slot, fresh) for k, v in cache.items()}

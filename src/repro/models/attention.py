@@ -57,6 +57,14 @@ def _split_heads(x, n, d):
 # decode steps never re-round already-stored entries.  int8 stores one
 # value per byte; int4 nibble-packs pairs along the head dim.
 
+def is_kv_node(node) -> bool:
+    """True for one layer-stack's attention cache: per-head K/V leaves
+    (``k``, ``v`` [, scales]) or the latent leaves of multi-head latent
+    attention (``ckv``, ``kpe`` [, scales]; ``models.mla``)."""
+    return isinstance(node, dict) and (
+        ("k" in node and "v" in node) or "ckv" in node)
+
+
 def cache_bits(cache) -> int:
     """Storage precision of a KV cache dict: 32 (float), 8, or 4.
 
@@ -266,8 +274,9 @@ def _mask_for(q_pos, kv_pos, causal, window, kv_len):
 
 def blockwise_attention_core(q, k, v, q_pos, kv_pos, *, causal=True,
                              window=0, attn_softcap=0.0, kv_len=None,
-                             q_block: int = 512,
-                             kv_block: int = 1024) -> jnp.ndarray:
+                             q_block: int = 512, kv_block: int = 1024,
+                             sm_scale: Optional[float] = None
+                             ) -> jnp.ndarray:
     """Flash-style memory-efficient attention: never materializes (S, T).
 
     Outer scan over query blocks, inner scan over KV blocks with running
@@ -275,7 +284,7 @@ def blockwise_attention_core(q, k, v, q_pos, kv_pos, *, causal=True,
     (autodiff through the scans; layer-level remat bounds residuals).
     """
     b, s, h, dh = q.shape
-    t, kv = k.shape[1], k.shape[2]
+    t, kv, dv = k.shape[1], k.shape[2], v.shape[-1]
     g = h // kv
     q_block = min(q_block, s)
     kv_block = min(kv_block, t)
@@ -285,9 +294,9 @@ def blockwise_attention_core(q, k, v, q_pos, kv_pos, *, causal=True,
     qg = q.reshape(b, nq, q_block, kv, g, dh)
     qp = q_pos.reshape(b, nq, q_block)
     kb = k.reshape(b, nk, kv_block, kv, dh)
-    vb = v.reshape(b, nk, kv_block, kv, dh)
+    vb = v.reshape(b, nk, kv_block, kv, dv)
     kp = kv_pos.reshape(b, nk, kv_block)
-    scale = 1.0 / jnp.sqrt(dh)
+    scale = 1.0 / jnp.sqrt(dh) if sm_scale is None else sm_scale
 
     def q_step(_, q_in):
         qi, qpi = q_in                       # (b,qb,kv,g,dh), (b,qb)
@@ -311,7 +320,7 @@ def blockwise_attention_core(q, k, v, q_pos, kv_pos, *, causal=True,
 
         m0 = jnp.full((b, kv, g, q_block), NEG_INF, jnp.float32)
         l0 = jnp.zeros((b, kv, g, q_block), jnp.float32)
-        a0 = jnp.zeros((b, kv, g, q_block, dh), jnp.float32)
+        a0 = jnp.zeros((b, kv, g, q_block, dv), jnp.float32)
         (m, l, acc), _ = jax.lax.scan(
             jax.checkpoint(kv_step),
             (m0, l0, a0),
@@ -324,20 +333,23 @@ def blockwise_attention_core(q, k, v, q_pos, kv_pos, *, causal=True,
     _, outs = jax.lax.scan(
         q_step, None,
         (jnp.moveaxis(qg, 1, 0), jnp.moveaxis(qp, 1, 0)))
-    out = jnp.moveaxis(outs, 0, 1)           # (b, nq, qb, kv, g, dh)
-    return out.reshape(b, s, h, dh).astype(q.dtype)
+    out = jnp.moveaxis(outs, 0, 1)           # (b, nq, qb, kv, g, dv)
+    return out.reshape(b, s, h, dv).astype(q.dtype)
 
 
 def attention_core(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                    q_pos: jnp.ndarray, kv_pos: jnp.ndarray,
                    *, causal: bool = True, window: int = 0,
                    attn_softcap: float = 0.0,
-                   kv_len: Optional[jnp.ndarray] = None) -> jnp.ndarray:
-    """q: (B,S,H,dh), k/v: (B,T,KV,dh), positions: (B,S)/(B,T) -> (B,S,H,dh).
+                   kv_len: Optional[jnp.ndarray] = None,
+                   sm_scale: Optional[float] = None) -> jnp.ndarray:
+    """q: (B,S,H,dh), k: (B,T,KV,dh), v: (B,T,KV,dv), positions:
+    (B,S)/(B,T) -> (B,S,H,dv).
 
     ``window > 0`` restricts attention to the last ``window`` positions
-    (Gemma-2 local layers); ``kv_len`` masks cache tails during decode.
-    Large (S x T) problems dispatch to the blockwise flash-style core.
+    (Gemma-2 local layers); ``kv_len`` masks cache tails during decode;
+    ``sm_scale`` replaces the softmax scale ``dh**-0.5``.  Large (S x T)
+    problems dispatch to the blockwise flash-style core.
     """
     qb, kb = ATTN_OPTS["q_block"], ATTN_OPTS["kv_block"]
     if q.shape[1] * k.shape[1] > ATTN_OPTS["min_elems"] and \
@@ -345,21 +357,24 @@ def attention_core(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         return blockwise_attention_core(
             q, k, v, q_pos, kv_pos, causal=causal, window=window,
             attn_softcap=attn_softcap, kv_len=kv_len,
-            q_block=qb, kv_block=kb)
+            q_block=qb, kv_block=kb, sm_scale=sm_scale)
     b, s, h, dh = q.shape
     t, kv = k.shape[1], k.shape[2]
     g = h // kv
     qg = q.reshape(b, s, kv, g, dh)
     scores = jnp.einsum("bskgd,btkd->bkgst", qg, k,
                         preferred_element_type=jnp.float32)
-    scores = scores / jnp.sqrt(dh).astype(scores.dtype)
+    if sm_scale is None:
+        scores = scores / jnp.sqrt(dh).astype(scores.dtype)
+    else:
+        scores = scores * sm_scale
     if attn_softcap:
         scores = _softcap(scores, attn_softcap)
     mask = _mask_for(q_pos, kv_pos, causal, window, kv_len)
     scores = jnp.where(mask[:, None, None, :, :], scores, NEG_INF)
     w = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
     out = jnp.einsum("bkgst,btkd->bskgd", w, v)
-    return out.reshape(b, s, h, dh)
+    return out.reshape(b, s, h, v.shape[-1])
 
 
 def attn_forward(p: Dict, x: jnp.ndarray, positions: jnp.ndarray, *,

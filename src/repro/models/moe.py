@@ -135,9 +135,15 @@ def init_moe(key, d_model: int, d_ff: int, n_experts: int, top_k: int,
     return p
 
 
-def moe_forward(p: Dict, x: jnp.ndarray, top_k: int
-                ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+def moe_forward(p: Dict, x: jnp.ndarray, top_k: int, *, offset: int = 0,
+                renorm: bool = True) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """x: (B, S, D) -> (out, aux_loss).
+
+    The router scores every expert (``router_w``'s width); the expert
+    weights hold ``n_held`` of them, ids ``offset .. offset + n_held - 1``,
+    and the layer adds only their part of the routed result (plus the
+    shared experts, which every holder computes).  ``renorm`` divides the
+    top-k gates by their sum.
 
     Under an active mesh this dispatches to the shard_map path: routing +
     sort stay LOCAL to each data shard (a global argsort under pjit would
@@ -146,54 +152,85 @@ def moe_forward(p: Dict, x: jnp.ndarray, top_k: int
     """
     mesh = get_mesh()
     if mesh is not None and mesh.devices.size > 1:
-        return _moe_forward_sharded(p, x, top_k, mesh)
-    return _moe_forward_local(p, x, top_k)
-
-
-def _moe_forward_local(p: Dict, x: jnp.ndarray, top_k: int
-                       ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    b, s, d = x.shape
-    e = p["router_w"].shape[-1]
-    xt = x.reshape(b * s, d)
-    logits = xt.astype(jnp.float32) @ p["router_w"]          # (T, E)
-    probs = jax.nn.softmax(logits, axis=-1)
-    gate_vals, expert_idx = jax.lax.top_k(probs, top_k)      # (T, k)
-    gate_vals = gate_vals / jnp.sum(gate_vals, -1, keepdims=True)
-
-    t = b * s
-    flat_expert = expert_idx.reshape(-1)                     # (T*k,)
-    flat_token = jnp.repeat(jnp.arange(t), top_k)
-    flat_gate = gate_vals.reshape(-1)
-
-    order = jnp.argsort(flat_expert)                         # stable
-    tok_sorted = flat_token[order]
-    xs = jnp.take(xt, tok_sorted, axis=0)                    # (T*k, D)
-    group_sizes = jnp.bincount(flat_expert, length=e).astype(jnp.int32)
-
-    gate = _grouped(xs, p["expert_gate"], group_sizes)
-    up = _grouped(xs, p["expert_up"], group_sizes)
-    h = jax.nn.silu(gate) * up
-    h = constraint(h, None, "ff")
-    ys = _grouped(h, p["expert_down"], group_sizes)      # (T*k, D)
-
-    ys = ys * flat_gate[order][:, None].astype(ys.dtype)
-    out = jnp.zeros_like(xt).at[tok_sorted].add(ys)
-    out = out.reshape(b, s, d)
-
+        out, aux = _moe_forward_sharded(p, x, top_k, mesh, offset, renorm)
+    else:
+        out, aux = _moe_forward_local(p, x, top_k, offset, renorm)
     if "shared_gate" in p:
-        hs = jax.nn.silu(qmatmul(x, p["shared_gate"])) \
-            * qmatmul(x, p["shared_up"])
-        out = out + qmatmul(hs, p["shared_down"])
-
-    # load-balance auxiliary loss (Switch-style)
-    frac_tokens = jnp.mean(
-        (jax.nn.one_hot(expert_idx, e).sum(1) > 0).astype(jnp.float32), 0)
-    frac_probs = jnp.mean(probs, axis=0)
-    aux = e * jnp.sum(frac_tokens * frac_probs)
+        with jax.named_scope("moe_shared"):
+            hs = jax.nn.silu(qmatmul(x, p["shared_gate"])) \
+                * qmatmul(x, p["shared_up"])
+            hs = constraint(hs, "batch", None, "ff")
+            out = out + qmatmul(hs, p["shared_down"])
     return out, aux
 
 
-def _moe_forward_sharded(p: Dict, x: jnp.ndarray, top_k: int, mesh
+def route(xt: jnp.ndarray, router_w, top_k: int, renorm: bool = True):
+    """Softmax gates over every expert: (gates (T, k), expert ids (T, k),
+    probs (T, E)).  ``renorm`` divides the top-k gates by their sum."""
+    logits = xt.astype(jnp.float32) @ router_w
+    probs = jax.nn.softmax(logits, axis=-1)
+    gates, idx = jax.lax.top_k(probs, top_k)
+    if renorm:
+        gates = gates / jnp.sum(gates, -1, keepdims=True)
+    return gates, idx, probs
+
+
+def expert_share(xt: jnp.ndarray, gates, idx, wg, wu, wd, offset=0,
+                 capacity=None) -> jnp.ndarray:
+    """The held experts' part of the routed output, dropless: the sum,
+    over the (token, choice) pairs routed to an expert ``offset ..
+    offset + n_held - 1`` (``n_held`` = ``wg``'s leading dim), of gate
+    times that expert's SwiGLU on the token.  Pairs routed elsewhere sort
+    after every held expert's pairs, past the last group, and their rows
+    are masked to zero: ``ragged_dot`` leaves the rows past its last
+    group unwritten on the TPU (garbage, NaN among it).  ``capacity``
+    bounds each expert's tokens (the GShard capacity path, which drops
+    the rest); None takes the module's ``GROUPED_IMPL``.  One chip passes
+    its held range; an expert-parallel rank passes its own."""
+    t, k = idx.shape
+    held = wg.shape[-3]
+    local = idx.reshape(-1) - offset
+    mine = (local >= 0) & (local < held)
+    grp = jnp.where(mine, local, held)
+    order = jnp.argsort(grp)                                 # stable
+    tok = jnp.repeat(jnp.arange(t), k)[order]
+    xs = jnp.take(xt, tok, axis=0)                           # (T*k, D)
+    sizes = jnp.bincount(grp, length=held + 1)[:held].astype(jnp.int32)
+    if capacity is None:
+        grouped = _grouped
+    else:
+        def grouped(x, w, gs):
+            return grouped_matmul_capacity(x, w, gs, capacity)
+    h = jax.nn.silu(grouped(xs, wg, sizes)) * grouped(xs, wu, sizes)
+    ys = grouped(h, wd, sizes)
+    gate = jnp.where(mine, gates.reshape(-1), 0.0)[order]
+    ys = jnp.where(mine[order][:, None],
+                   ys * gate[:, None].astype(ys.dtype), 0)
+    return jnp.zeros_like(xt).at[tok].add(ys)
+
+
+def _aux_loss(probs, idx):
+    """Switch-style load-balance loss over every expert."""
+    e = probs.shape[-1]
+    frac_tokens = jnp.mean(
+        (jax.nn.one_hot(idx, e).sum(1) > 0).astype(jnp.float32), 0)
+    return e * jnp.sum(frac_tokens * jnp.mean(probs, axis=0))
+
+
+def _moe_forward_local(p: Dict, x: jnp.ndarray, top_k: int, offset: int,
+                       renorm: bool) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    b, s, d = x.shape
+    xt = x.reshape(b * s, d)
+    with jax.named_scope("moe_route"):
+        gates, idx, probs = route(xt, p["router_w"], top_k, renorm)
+    with jax.named_scope("moe_experts"):
+        out = expert_share(xt, gates, idx, p["expert_gate"],
+                           p["expert_up"], p["expert_down"], offset)
+    return out.reshape(b, s, d), _aux_loss(probs, idx)
+
+
+def _moe_forward_sharded(p: Dict, x: jnp.ndarray, top_k: int, mesh,
+                         offset: int, renorm: bool
                          ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """shard_map MoE: per-data-shard routing/sort + in-expert TP on 'model'.
 
@@ -209,11 +246,18 @@ def _moe_forward_sharded(p: Dict, x: jnp.ndarray, top_k: int, mesh
     has_model = "model" in mesh.axis_names
     e = p["router_w"].shape[-1]
     model_size = mesh.shape.get("model", 1)
-    # TRUE expert parallelism when E divides the model axis: each model
-    # rank owns E/model experts outright (weights never gathered); tokens
-    # are data-sharded and every rank computes only its experts' share.
-    # Otherwise fall back to in-expert tensor parallelism on d_ff.
-    ep_mode = has_model and e % model_size == 0
+    # shard_map needs dense (E, K, N) operands with one spec per array;
+    # packed expert execution under shard_map is future work, so quantized
+    # experts dequantize here through the sanctioned common.qdense entry.
+    wg = qdense(p["expert_gate"], x.dtype)
+    wu = qdense(p["expert_up"], x.dtype)
+    wd = qdense(p["expert_down"], x.dtype)
+    # TRUE expert parallelism when the held experts divide the model axis:
+    # each model rank owns held/model experts outright (weights never
+    # gathered); tokens are data-sharded and every rank computes only its
+    # experts' share.  Otherwise fall back to in-expert tensor
+    # parallelism on d_ff.
+    ep_mode = has_model and wg.shape[0] % model_size == 0
 
     def reshard(w, spec):
         return jax.lax.with_sharding_constraint(w, NamedSharding(mesh, spec))
@@ -224,79 +268,28 @@ def _moe_forward_sharded(p: Dict, x: jnp.ndarray, top_k: int, mesh
     else:
         wspec_g = wspec_u = P(None, None, "model" if has_model else None)
         wspec_d = P(None, "model" if has_model else None, None)
-    # shard_map needs dense (E, K, N) operands with one spec per array;
-    # packed expert execution under shard_map is future work, so quantized
-    # experts dequantize here through the sanctioned common.qdense entry.
-    wg = reshard(qdense(p["expert_gate"], x.dtype), wspec_g)
-    wu = reshard(qdense(p["expert_up"], x.dtype), wspec_u)
-    wd = reshard(qdense(p["expert_down"], x.dtype), wspec_d)
+    wg = reshard(wg, wspec_g)
+    wu = reshard(wu, wspec_u)
+    wd = reshard(wd, wspec_d)
     rw = reshard(p["router_w"], P())
 
     def local_moe(xs, rw, wg, wu, wd):
         b, s, d = xs.shape
         xt = xs.reshape(b * s, d)
-        t = b * s
-        logits = xt.astype(jnp.float32) @ rw
-        probs = jax.nn.softmax(logits, axis=-1)
-        gate_vals, expert_idx = jax.lax.top_k(probs, top_k)
-        gate_vals = gate_vals / jnp.sum(gate_vals, -1, keepdims=True)
-        flat_expert = expert_idx.reshape(-1)
-        flat_token = jnp.repeat(jnp.arange(t), top_k)
-        flat_gate = gate_vals.reshape(-1)
-        order = jnp.argsort(flat_expert)
-        tok_sorted = flat_token[order]
-        xsrt = jnp.take(xt, tok_sorted, axis=0)
-        group_sizes = jnp.bincount(flat_expert, length=e).astype(jnp.int32)
+        gates, idx, probs = route(xt, rw, top_k, renorm)
         if ep_mode:
-            # compute only this rank's expert range over the sorted tokens
-            e_local = wg.shape[0]
-            rank = jax.lax.axis_index("model")
-            offs = rank * e_local
-            gs_local = jax.lax.dynamic_slice(group_sizes, (offs,), (e_local,))
-            starts = jnp.concatenate(
-                [jnp.zeros((1,), jnp.int32),
-                 jnp.cumsum(group_sizes)[:-1].astype(jnp.int32)])
-            start0 = jax.lax.dynamic_slice(starts, (offs,), (1,))[0]
-            # roll so this rank's tokens start at row 0
-            xloc = jnp.roll(xsrt, -start0, axis=0)
-            if GROUPED_IMPL["impl"] == "ragged":
-                # exact/no-drop EP dispatch (honors the impl flag): append
-                # a zero dummy expert whose group absorbs the other ranks'
-                # tokens, so every local token is computed regardless of
-                # routing skew and the psum over 'model' reassembles the
-                # single-device exact output.
-                rest = (jnp.asarray(xloc.shape[0], jnp.int32)
-                        - jnp.sum(gs_local).astype(jnp.int32))[None]
-                gs_ext = jnp.concatenate([gs_local, rest])
-
-                def _ext(w):
-                    return jnp.concatenate(
-                        [w, jnp.zeros((1,) + w.shape[1:], w.dtype)])
-
-                gate = grouped_matmul(xloc, _ext(wg), gs_ext)
-                up = grouped_matmul(xloc, _ext(wu), gs_ext)
-                h = jax.nn.silu(gate) * up
-                ys = grouped_matmul(h, _ext(wd), gs_ext)
-            else:
-                cap = _capacity(xt.shape[0] * top_k, e)
-                gate = grouped_matmul_capacity(xloc, wg, gs_local, cap)
-                up = grouped_matmul_capacity(xloc, wu, gs_local, cap)
-                h = jax.nn.silu(gate) * up
-                ys = grouped_matmul_capacity(h, wd, gs_local, cap)
-            ys = jnp.roll(ys, start0, axis=0)
+            # this rank's expert range; exact/no-drop unless the capacity
+            # impl is selected, and the psum over 'model' reassembles the
+            # single-device output
+            rank_offset = offset + jax.lax.axis_index("model") * wg.shape[0]
+            cap = _capacity(xt.shape[0] * top_k, e) \
+                if GROUPED_IMPL["impl"] == "capacity" else None
+            out = expert_share(xt, gates, idx, wg, wu, wd, rank_offset, cap)
         else:
-            gate = _grouped(xsrt, wg, group_sizes)
-            up = _grouped(xsrt, wu, group_sizes)
-            h = jax.nn.silu(gate) * up
-            ys = _grouped(h, wd, group_sizes)
-        ys = ys * flat_gate[order][:, None].astype(ys.dtype)
-        out = jnp.zeros_like(xt).at[tok_sorted].add(ys)
+            out = expert_share(xt, gates, idx, wg, wu, wd, offset)
         if has_model:
             out = jax.lax.psum(out, "model")
-        frac_tokens = jnp.mean(
-            (jax.nn.one_hot(expert_idx, e).sum(1) > 0).astype(jnp.float32), 0)
-        frac_probs = jnp.mean(probs, axis=0)
-        aux = e * jnp.sum(frac_tokens * frac_probs)
+        aux = _aux_loss(probs, idx)
         aux = jax.lax.pmean(aux, dp) if dp else aux
         if has_model:
             aux = jax.lax.pmean(aux, "model")  # replicate for out_specs
@@ -311,13 +304,7 @@ def _moe_forward_sharded(p: Dict, x: jnp.ndarray, top_k: int, mesh
                       P(None, mdl, None))
     in_specs = (P(dpa, None, None), P()) + w_in_specs
     out_specs = (P(dpa, None, None), P())
-    out, aux = jax.shard_map(local_moe, mesh=mesh, in_specs=in_specs,
+    with jax.named_scope("moe_experts"):
+        return jax.shard_map(local_moe, mesh=mesh, in_specs=in_specs,
                              out_specs=out_specs, check_vma=False)(
-        x, rw, wg, wu, wd)
-
-    if "shared_gate" in p:
-        hs = jax.nn.silu(qmatmul(x, p["shared_gate"])) \
-            * qmatmul(x, p["shared_up"])
-        hs = constraint(hs, "batch", None, "ff")
-        out = out + qmatmul(hs, p["shared_down"])
-    return out, aux
+            x, rw, wg, wu, wd)

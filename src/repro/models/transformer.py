@@ -17,10 +17,11 @@ import jax.numpy as jnp
 
 from ..configs.base import ModelConfig
 from ..dist.sharding import constraint, shard_params_tree
-from .attention import attn_forward, init_attn
+from .attention import attn_forward, init_attn, is_kv_node
 from .common import (act_quant, embed_init, make_weight,
                      prepare_params, qmatmul, rms_norm, softcap)
 from .ffn import init_mlp, mlp_forward
+from .mla import init_mla, make_latent_cache, mla_forward
 from .moe import moe_forward
 from .rwkv import init_rwkv6, rwkv6_forward, rwkv6_init_state
 from .ssm import init_mamba2, mamba2_forward, mamba2_init_state
@@ -36,9 +37,13 @@ SCOPES = (
     "attn_in",     # pre-attention norm, activation quant, q/k/v, rope
     "kv_write",    # quantize the new K/V and write it into the cache
     "attn_core",   # read the cache and attend (paged kernel or gather)
+    "latent_attn",  # MLA decode: latent scores and sum over the cache
     "attn_out",    # output projection, post-norm, residual
     "kv_update",   # the layer's cache written back into the stack
     "mlp",         # pre-MLP norm, activation quant, MLP or experts
+    "moe_route",   # router logits, softmax, top-k gates
+    "moe_experts",  # the held routed experts' part of the output
+    "moe_shared",  # the shared experts
     "head",        # final norm, output head, softcap
 )
 
@@ -74,8 +79,11 @@ def scan_or_loop(body, carry, xs, use_scan: bool, length: int):
 # init
 # ---------------------------------------------------------------------------
 
-def _init_block(key, cfg: ModelConfig, stack: int) -> Dict:
-    """One stacked parameter set for ``stack`` homogeneous layers."""
+def _init_block(key, cfg: ModelConfig, stack: int,
+                dense: bool = False) -> Dict:
+    """One stacked parameter set for ``stack`` homogeneous layers;
+    ``dense`` gives a MoE model's leading dense layers (an MLP of width
+    ``cfg.dense_d_ff``)."""
     qc = cfg.quant
     dt = jnp.float32
     ks = jax.random.split(key, 8)
@@ -87,28 +95,32 @@ def _init_block(key, cfg: ModelConfig, stack: int) -> Dict:
         if cfg.use_post_norms:
             p["ln_attn_post"] = jnp.zeros((stack, d), dt)
             p["ln_mlp_post"] = jnp.zeros((stack, d), dt)
-        p["attn"] = {
-            "wq": make_weight(ks[0], (stack, d, cfg.n_heads * dh), qc, dtype=dt),
-            "wk": make_weight(ks[1], (stack, d, cfg.n_kv_heads * dh), qc, dtype=dt),
-            "wv": make_weight(ks[2], (stack, d, cfg.n_kv_heads * dh), qc, dtype=dt),
-            "wo": make_weight(ks[3], (stack, cfg.n_heads * dh, d), qc, dtype=dt),
-        }
+        if cfg.kv_lora_rank:
+            p["attn"] = init_mla(ks[0], cfg, stack, dtype=dt)
+        else:
+            p["attn"] = {
+                "wq": make_weight(ks[0], (stack, d, cfg.n_heads * dh), qc, dtype=dt),
+                "wk": make_weight(ks[1], (stack, d, cfg.n_kv_heads * dh), qc, dtype=dt),
+                "wv": make_weight(ks[2], (stack, d, cfg.n_kv_heads * dh), qc, dtype=dt),
+                "wo": make_weight(ks[3], (stack, cfg.n_heads * dh, d), qc, dtype=dt),
+            }
         if cfg.qkv_bias:
             p["attn"]["bq"] = jnp.zeros((stack, cfg.n_heads * dh), dt)
             p["attn"]["bk"] = jnp.zeros((stack, cfg.n_kv_heads * dh), dt)
             p["attn"]["bv"] = jnp.zeros((stack, cfg.n_kv_heads * dh), dt)
-        if cfg.family == "moe" or cfg.n_experts:
+        if (cfg.family == "moe" or cfg.n_experts) and not dense:
+            e = cfg.held_experts
             p["moe"] = {
                 "router_w": jax.random.normal(
                     ks[4], (stack, d, cfg.n_experts), jnp.float32) * 0.02,
                 "expert_gate": make_weight(
-                    ks[5], (stack, cfg.n_experts, d, cfg.d_ff), qc, dtype=dt),
+                    ks[5], (stack, e, d, cfg.d_ff), qc, dtype=dt),
                 "expert_up": make_weight(
                     jax.random.fold_in(ks[5], 1),
-                    (stack, cfg.n_experts, d, cfg.d_ff), qc, dtype=dt),
+                    (stack, e, d, cfg.d_ff), qc, dtype=dt),
                 "expert_down": make_weight(
                     jax.random.fold_in(ks[5], 2),
-                    (stack, cfg.n_experts, cfg.d_ff, d), qc, dtype=dt),
+                    (stack, e, cfg.d_ff, d), qc, dtype=dt),
             }
             if cfg.n_shared_experts:
                 f = cfg.n_shared_experts * cfg.d_ff
@@ -119,16 +131,17 @@ def _init_block(key, cfg: ModelConfig, stack: int) -> Dict:
                 p["moe"]["shared_down"] = make_weight(
                     jax.random.fold_in(ks[6], 2), (stack, f, d), qc, dtype=dt)
         else:
+            ff = cfg.dense_d_ff if dense else cfg.d_ff
             if cfg.mlp_kind == "swiglu":
                 p["mlp"] = {
-                    "w_gate": make_weight(ks[4], (stack, d, cfg.d_ff), qc, dtype=dt),
-                    "w_up": make_weight(ks[5], (stack, d, cfg.d_ff), qc, dtype=dt),
-                    "w_down": make_weight(ks[6], (stack, cfg.d_ff, d), qc, dtype=dt),
+                    "w_gate": make_weight(ks[4], (stack, d, ff), qc, dtype=dt),
+                    "w_up": make_weight(ks[5], (stack, d, ff), qc, dtype=dt),
+                    "w_down": make_weight(ks[6], (stack, ff, d), qc, dtype=dt),
                 }
             else:
                 p["mlp"] = {
-                    "w_in": make_weight(ks[4], (stack, d, cfg.d_ff), qc, dtype=dt),
-                    "w_out": make_weight(ks[5], (stack, cfg.d_ff, d), qc, dtype=dt),
+                    "w_in": make_weight(ks[4], (stack, d, ff), qc, dtype=dt),
+                    "w_out": make_weight(ks[5], (stack, ff, d), qc, dtype=dt),
                 }
         if qc.enabled and qc.act_bits < 32:
             p["beta_attn"] = jnp.full((stack,), qc.pact_init, dt)
@@ -150,8 +163,13 @@ def init_lm(key, cfg: ModelConfig) -> Dict:
     params: Dict[str, Any] = {
         "embed": embed_init(ks[0], cfg.vocab, d, dt),
         "final_norm": jnp.zeros((d,), dt),
-        "layers": _init_block(ks[1], cfg, cfg.n_layers),
+        "layers": _init_block(ks[1], cfg,
+                              cfg.n_layers - cfg.first_dense_layers),
     }
+    if cfg.first_dense_layers:
+        params["dense_layers"] = _init_block(ks[3], cfg,
+                                             cfg.first_dense_layers,
+                                             dense=True)
     if not cfg.tie_embeddings:
         params["lm_head"] = make_weight(
             ks[2], (d, cfg.vocab), cfg.quant, dtype=dt,
@@ -198,6 +216,11 @@ def _attn_block(lp, h, positions, cfg: ModelConfig, is_local,
         x = act_quant(x, lp.get("beta_attn"), qc)
         window = jnp.where(is_local, cfg.sliding_window, 0) if \
             cfg.alt_local_global else (cfg.sliding_window or 0)
+    if cfg.kv_lora_rank:
+        out, new_cache = mla_forward(lp["attn"], x, positions, cfg,
+                                     cache=cache, cache_index=index)
+        with jax.named_scope("attn_out"):
+            return h + out, new_cache
     # window as traced value: attention uses dynamic comparison, so pass
     # the array directly (0 disables).
     out, new_cache = attn_forward(
@@ -218,7 +241,9 @@ def _mlp_block(lp, h, cfg: ModelConfig):
     x = act_quant(x, lp.get("beta_mlp"), qc)
     aux = jnp.asarray(0.0, jnp.float32)
     if "moe" in lp:
-        out, aux = moe_forward(lp["moe"], x, cfg.top_k)
+        out, aux = moe_forward(lp["moe"], x, cfg.top_k,
+                               offset=cfg.expert_offset,
+                               renorm=cfg.norm_topk_prob)
     else:
         out = mlp_forward(lp["mlp"], x, cfg.mlp_kind)
     if cfg.use_post_norms:
@@ -231,11 +256,9 @@ def _mlp_block(lp, h, cfg: ModelConfig):
 # ---------------------------------------------------------------------------
 
 def _walk_dense(mp, cfg, h, positions, cache, index):
-    """Scan over homogeneous attention+FFN layers."""
-    n = cfg.n_layers
-    is_local = (jnp.arange(n) % 2 == 0) if cfg.alt_local_global else \
-        jnp.zeros((n,), bool)
-
+    """Scan over homogeneous attention+FFN layers: a MoE model's leading
+    dense layers (``dense_layers``) first, then ``layers``; the stacked
+    cache holds every layer, in that order."""
     def body(carry, xs):
         # cache rides in the carry and is updated in place per layer —
         # scan carries alias buffers, so the KV cache is never duplicated
@@ -254,10 +277,20 @@ def _walk_dense(mp, cfg, h, positions, cache, index):
 
     if cfg.remat:
         body = jax.checkpoint(body)
-    (h, aux, new_cache, _), _ = scan_or_loop(
-        body, (h, jnp.asarray(0.0, jnp.float32), cache,
-               jnp.asarray(0, jnp.int32)),
-        (mp["layers"], is_local), cfg.scan_layers, n)
+    carry = (h, jnp.asarray(0.0, jnp.float32), cache,
+             jnp.asarray(0, jnp.int32))
+    first = 0
+    for name in ("dense_layers", "layers"):
+        if name not in mp:
+            continue
+        n = jax.tree_util.tree_leaves(mp[name])[0].shape[0]
+        layer = first + jnp.arange(n)
+        is_local = (layer % 2 == 0) if cfg.alt_local_global else \
+            jnp.zeros((n,), bool)
+        carry, _ = scan_or_loop(body, carry, (mp[name], is_local),
+                                cfg.scan_layers, n)
+        first += n
+    h, aux, new_cache, _ = carry
     return h, aux, new_cache
 
 
@@ -401,6 +434,8 @@ def make_kv_cache(cfg: ModelConfig, stack: int, batch: int,
 
     <32 bits stores quantized-at-rest entries (int8, or int4 nibble-packed
     along the head dim) plus per-token/head scales; see models.attention."""
+    if cfg.kv_lora_rank:
+        return make_latent_cache(cfg, stack, batch, max_len, _cdtype(cfg))
     dh, kv = cfg.head_dim, cfg.n_kv_heads
     shape = (stack, batch, max_len, kv, dh)
     bits = cfg.kv_cache_bits
@@ -421,11 +456,12 @@ def make_kv_cache(cfg: ModelConfig, stack: int, batch: int,
 
 
 def _walk_cache_tree(cache, kv_fn, leaf_fn):
-    """Apply ``kv_fn`` to KV sub-dicts ({"k","v",...} leaves with a time
-    axis) and ``leaf_fn`` to recurrent leaves (no time axis) of a stacked
-    decode-cache tree (works on arrays or ShapeDtypeStructs)."""
+    """Apply ``kv_fn`` to KV sub-dicts (``attention.is_kv_node``: leaves
+    with a time axis) and ``leaf_fn`` to recurrent leaves (no time axis)
+    of a stacked decode-cache tree (works on arrays or
+    ShapeDtypeStructs)."""
     if isinstance(cache, dict):
-        if "k" in cache and "v" in cache:
+        if is_kv_node(cache):
             return kv_fn(cache)
         return {k: _walk_cache_tree(v, kv_fn, leaf_fn)
                 for k, v in cache.items()}
@@ -461,8 +497,8 @@ def paginate_cache_tree(cache, n_slots: int, n_pages: int, page_size: int,
             "pages": {n: jnp.zeros((l.shape[0], n_pages, page_size,
                                     *l.shape[3:]), l.dtype)
                       for n, l in node.items()},
-            "table": jnp.zeros((node["k"].shape[0], n_slots, nb),
-                               jnp.int32)},
+            "table": jnp.zeros((next(iter(node.values())).shape[0],
+                                n_slots, nb), jnp.int32)},
         lambda l: jnp.zeros((l.shape[0], n_slots, *l.shape[2:]), l.dtype))
 
 

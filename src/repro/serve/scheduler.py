@@ -99,6 +99,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..models.attention import is_kv_node
 from .sampling import GenerationResult, Request, sample_token
 
 _span = jax.profiler.TraceAnnotation
@@ -329,7 +330,7 @@ def _paged_pool_bytes(cache) -> int:
 def _kv_resident_bytes(cache) -> int:
     """At-rest bytes of a contiguous cache's KV leaves (k/v + scales)."""
     if isinstance(cache, dict):
-        if "k" in cache and "v" in cache:
+        if is_kv_node(cache):
             return sum(int(leaf.nbytes) for leaf in
                        jax.tree_util.tree_leaves(cache))
         return sum(_kv_resident_bytes(v) for v in cache.values())

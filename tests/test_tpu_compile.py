@@ -88,3 +88,21 @@ def test_paged_attention_compiles(one_chip, bits, slots, blocks, n_pages):
 def test_pact_compiles(one_chip):
     _compile(lambda x, b: pact_quant_pallas(x, b, interpret=False), "pact",
              one_chip, ((16, K), jnp.float32), ((1,), jnp.float32))
+
+
+# bench/cells/dsv2lite-doc.json: DeepSeek-V2-Lite's latent cache (rank
+# 512, rope 64, 16 heads), 32 slots of 8192 positions in 16-token pages,
+# the 18432-page pool the cell builds; and a pool whose page count is not
+# a multiple of the 8-page scale block
+@pytest.mark.parametrize("slots,blocks,n_pages", [(32, 512, 18432),
+                                                  (SLOTS, BLOCKS, POOL)])
+def test_latent_attention_compiles(one_chip, slots, blocks, n_pages):
+    from repro.kernels.latent_attention import latent_attention
+    _compile(lambda ql, qp, c, cs, p, ps, t, n: latent_attention(
+                 ql, qp, c, cs, p, ps, t, n, sm_scale=0.1147,
+                 interpret=False),
+             "latent_attention", one_chip,
+             ((slots, 16, 512), jnp.float32), ((slots, 16, 64), jnp.float32),
+             ((n_pages, PAGE, 512), jnp.int8), ((n_pages, PAGE), jnp.float32),
+             ((n_pages, PAGE, 64), jnp.int8), ((n_pages, PAGE), jnp.float32),
+             ((slots, blocks), jnp.int32), ((slots,), jnp.int32))
