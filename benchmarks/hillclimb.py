@@ -44,24 +44,29 @@ def run_paged_attn_variant(tag: str, block_kv: int, b: int = 8,
     import jax.numpy as jnp
 
     from repro.kernels import paged_attention
+    from repro.kernels.kv_pages import PageFormat, to_rows
 
     n_pages = 1 + b * nb
     ks = jax.random.split(jax.random.PRNGKey(11), 5)
     q = jax.random.normal(ks[0], (b, kv, g, dh), jnp.float32)
-    kp = jax.random.randint(ks[1], (n_pages, page, kv, dh),
-                            -127, 128).astype(jnp.int8)
-    vp = jax.random.randint(ks[2], (n_pages, page, kv, dh),
-                            -127, 128).astype(jnp.int8)
-    ksc = jax.random.uniform(ks[3], (n_pages, page, kv), jnp.float32,
-                             0.005, 0.02)
-    vsc = jax.random.uniform(ks[4], (n_pages, page, kv), jnp.float32,
-                             0.005, 0.02)
+
+    def stored(x):                       # one layer, kv_pages storage
+        w = x[0, 0].size
+        return to_rows(x.reshape(1, n_pages, page, w), PageFormat(page, w))
+    kp = stored(jax.random.randint(ks[1], (n_pages, page, kv, dh),
+                                   -127, 128).astype(jnp.int8))
+    vp = stored(jax.random.randint(ks[2], (n_pages, page, kv, dh),
+                                   -127, 128).astype(jnp.int8))
+    ksc = stored(jax.random.uniform(ks[3], (n_pages, page, kv), jnp.float32,
+                                    0.005, 0.02))
+    vsc = stored(jax.random.uniform(ks[4], (n_pages, page, kv), jnp.float32,
+                                    0.005, 0.02))
     table = jnp.arange(1, 1 + b * nb, dtype=jnp.int32).reshape(b, nb)
     kv_len = jnp.full((b,), nb * page, jnp.int32)
 
     def step():
         return paged_attention(q, kp, vp, ksc, vsc, table, kv_len,
-                               block_kv=block_kv)
+                               page=page, block_kv=block_kv)
 
     jax.block_until_ready(step())            # compile
     t0 = time.perf_counter()

@@ -12,6 +12,7 @@ from __future__ import annotations
 
 import argparse
 import json
+import math
 from typing import Dict, List
 
 import jax
@@ -102,33 +103,40 @@ def paged_attention_timings(b: int = 4, kv: int = 4, g: int = 2,
     import jax.numpy as jnp
 
     from repro.kernels import paged_attention
+    from repro.kernels.kv_pages import PageFormat, read_pages, to_rows
     from repro.kernels.ref import paged_attention_ref
-    from repro.models.attention import (attention_core, dequantize_kv,
-                                        paged_gather)
+    from repro.models.attention import attention_core, dequantize_kv
 
     n_pages = 1 + b * nb
     t_len = nb * page
     key = jax.random.PRNGKey(7)
     ks = jax.random.split(key, 5)
     q = jax.random.normal(ks[0], (b, kv, g, dh), jnp.float32)
-    kp = jax.random.randint(ks[1], (n_pages, page, kv, dh),
-                            -127, 128).astype(jnp.int8)
-    vp = jax.random.randint(ks[2], (n_pages, page, kv, dh),
-                            -127, 128).astype(jnp.int8)
-    ksc = jax.random.uniform(ks[3], (n_pages, page, kv), jnp.float32,
-                             0.005, 0.02)
-    vsc = jax.random.uniform(ks[4], (n_pages, page, kv), jnp.float32,
-                             0.005, 0.02)
+
+    def stored(x):                       # one layer, kv_pages storage
+        w = x[0, 0].size
+        return to_rows(x.reshape(1, n_pages, page, w), PageFormat(page, w))
+    kp = stored(jax.random.randint(ks[1], (n_pages, page, kv, dh),
+                                   -127, 128).astype(jnp.int8))
+    vp = stored(jax.random.randint(ks[2], (n_pages, page, kv, dh),
+                                   -127, 128).astype(jnp.int8))
+    ksc = stored(jax.random.uniform(ks[3], (n_pages, page, kv), jnp.float32,
+                                    0.005, 0.02))
+    vsc = stored(jax.random.uniform(ks[4], (n_pages, page, kv), jnp.float32,
+                                    0.005, 0.02))
     table = jax.numpy.arange(1, 1 + b * nb,
                              dtype=jnp.int32).reshape(b, nb)
     kv_len = jnp.full((b,), t_len, jnp.int32)
 
+    def gather(leaf, *tail):
+        w = math.prod(tail)
+        return read_pages(leaf, PageFormat(page, w), 0, table).reshape(
+            b, t_len, *tail)
+
     @jax.jit
     def gather_composite():
-        k = dequantize_kv(paged_gather(kp, table),
-                          paged_gather(ksc, table), jnp.float32)
-        v = dequantize_kv(paged_gather(vp, table),
-                          paged_gather(vsc, table), jnp.float32)
+        k = dequantize_kv(gather(kp, kv, dh), gather(ksc, kv), jnp.float32)
+        v = dequantize_kv(gather(vp, kv, dh), gather(vsc, kv), jnp.float32)
         q_core = q.reshape(b, 1, kv * g, dh)
         q_pos = jnp.full((b, 1), t_len - 1, jnp.int32)
         kv_pos = jnp.broadcast_to(jnp.arange(t_len)[None, :], (b, t_len))
@@ -137,11 +145,12 @@ def paged_attention_timings(b: int = 4, kv: int = 4, g: int = 2,
 
     return [
         _run("paged_attention_fused(interp)",
-             lambda: paged_attention(q, kp, vp, ksc, vsc, table, kv_len)),
+             lambda: paged_attention(q, kp, vp, ksc, vsc, table, kv_len,
+                                     page=page)),
         _run("paged_attention_gather", gather_composite),
         _run("paged_attention_ref",
              lambda: paged_attention_ref(q, kp, vp, ksc, vsc, table,
-                                         kv_len)),
+                                         kv_len, page=page)),
     ]
 
 

@@ -215,6 +215,7 @@ def check_kernels(cfg, params, nb):
     import jax
     import jax.numpy as jnp
     from repro.kernels import packed_matmul, paged_attention
+    from repro.kernels.kv_pages import PageFormat, to_rows
     from repro.kernels.ref import packed_matmul_ref, paged_attention_ref
     from repro.models.attention import quantize_kv
     ks = jax.random.split(jax.random.PRNGKey(SEED + 2), 6)
@@ -225,18 +226,25 @@ def check_kernels(cfg, params, nb):
     mm = (jax.random.normal(ks[0], (CHUNK, w.shape[-2])), w.w_int[0], scale)
     kv_len = jnp.asarray([1, PAGE + 3, nb * PAGE // 2, nb * PAGE], jnp.int32)
     b, kv, dh = len(kv_len), cfg.n_kv_heads, cfg.head_dim
-    pool = (1 + b * nb, PAGE, kv, dh)
-    k, k_scale = quantize_kv(jax.random.normal(ks[1], pool), 8)
-    v, v_scale = quantize_kv(jax.random.normal(ks[2], pool), 8)
+    pool = (2, 1 + b * nb, PAGE, kv, dh)          # two layers, read layer 1
+
+    def stored(x):                                # kernels.kv_pages storage
+        w = x[0, 0, 0].size
+        return to_rows(x.reshape(*pool[:3], w), PageFormat(PAGE, w))
+    k, k_scale = map(stored, quantize_kv(jax.random.normal(ks[1], pool), 8))
+    v, v_scale = map(stored, quantize_kv(jax.random.normal(ks[2], pool), 8))
     table = 1 + jax.random.permutation(ks[3], b * nb).reshape(b, nb)
     pa = (jax.random.normal(ks[4], (b, kv, cfg.n_heads // kv, dh)), k, v,
           k_scale, v_scale, table.astype(jnp.int32), kv_len)
+    kw = dict(page=PAGE, layer=1)
     got = {"packed_matmul": jax.jit(lambda *a: packed_matmul(*a, **geo))(*mm),
-           "paged_attention": jax.jit(paged_attention)(*pa)}
+           "paged_attention": jax.jit(
+               lambda *a: paged_attention(*a, **kw))(*pa)}
     with jax.default_matmul_precision("highest"):
         want = {"packed_matmul": jax.jit(lambda *a: packed_matmul_ref(
                     *a, **geo))(*mm),
-                "paged_attention": jax.jit(paged_attention_ref)(*pa)}
+                "paged_attention": jax.jit(
+                    lambda *a: paged_attention_ref(*a, **kw))(*pa)}
     return {name: rel_delta(got[name], want[name]) for name in got}
 
 
@@ -282,6 +290,12 @@ def four_chips(cfg, prompt_lens=PROMPT_LENS, max_new=MAX_NEW):
                              f"shards {shard_shapes} of {leaf.shape}")
     reqs = make_requests(cfg, prompt_lens, max_new)
     results, sched, wall = serve(sharded, reqs)
+    # the page pool's K/V lanes split over the mesh by KV heads
+    pool_k = sched.state["cache"]["pages"]["k"]
+    pool_shapes = {s.data.shape for s in pool_k.addressable_shards}
+    if pool_shapes == {pool_k.shape}:
+        raise AssertionError(f"page pool not split over the mesh: shards "
+                             f"{pool_shapes} of {pool_k.shape}")
     single = make_engine(api, params, validate=False)
     state1 = single.init_decode_state(reqs[0].inputs, sched.n_slots,
                                       sched.max_len, page_size=PAGE)
@@ -299,6 +313,7 @@ def four_chips(cfg, prompt_lens=PROMPT_LENS, max_new=MAX_NEW):
                 tokens=sum(len(r.tokens) for r in results),
                 sharded_prefill=delta, shard_shape=sorted(shard_shapes)[0],
                 full_shape=tuple(leaf.shape),
+                pool_shard_shape=sorted(pool_shapes)[0],
                 custom_calls={"prefill_chunk": text.count("tpu_custom_call")})
 
 

@@ -24,17 +24,19 @@ Rules (cross-referenced by the contract appendix in ``kernels/ops.py``):
 * ``BP2``  mask LUT: (..., bits, GR, GC) binary, prefix-monotone along
   the bit axis (occupancy = min(bw, bits) live LOW planes), f32.
 * ``BP3``  scale LUT: (..., GR, GC) f32, finite.
-* ``PC1``  paged cache: pool leaves agree on (stack, n_pages, page_size)
-  leading dims; ``table`` is integer (stack, n_slots, nb).
+* ``PC1``  paged cache: the pool is a ``kernels.kv_pages.PagePool`` whose
+  every leaf has the lane-dense page storage of its per-token shape,
+  ``PageFormat(page, width).shape(stack, n_pages)`` = (stack, blocks,
+  rows, lanes) with lanes a multiple of 128, the stack the table's;
+  ``table`` is integer (stack, n_slots, nb).
 * ``PC2``  block tables: every entry in [0, n_pages); page 0 is the
   reserved trash page; a non-zero page owned by two slots is flagged
   unless a refcount ledger (the scheduler's ``PrefixCache``) accounts
   for the sharing.
 * ``PC3``  quantized pools carry their per-token scale leaves.
 * ``PA1``  fused-kernel pool layout: ``k``/``v`` agree on dtype and full
-  shape; scale leaves match the payload's (stack, n_pages, page, KV)
-  prefix; an int4 (uint8) pool's packed head dim unpacks to an even
-  head dim (nibble pairs along dh).
+  shape; a scale leaf holds one f32 per token and KV head (per-token
+  shape (KV,), the payload's leading dim).
 * ``PA2``  pool capacity: >= 2 pages (the reserved trash page 0 plus at
   least one allocatable page) and >= 1 table block per slot.
 * ``PA3``  concrete block tables: each slot's live (non-zero) pages form
@@ -79,6 +81,7 @@ from typing import Any, List, Optional
 import jax
 import numpy as np
 
+from ..kernels.kv_pages import PagePool
 from .report import Finding
 
 _FLOATS = ("float32",)
@@ -323,22 +326,27 @@ def _walk_paged(cache, path, findings: List[Finding],
             c.err("PC1", "paged KV node has a table but no page pool",
                   "['pages']")
             return
-        heads = {name: _shape(leaf)[:3] for name, leaf in pages.items()}
-        first = next(iter(heads.values()))
-        for name, h in heads.items():
-            if len(h) < 3:
-                c.err("PC1", f"pool leaf must be (stack, n_pages, "
-                             f"page_size, ...), got {_shape(pages[name])}",
+        if not isinstance(pages, PagePool):
+            c.err("PC1", "page pool is a plain dict: it must be a "
+                         "kernels.kv_pages.PagePool, lane-dense leaves "
+                         "with their page geometry", "['pages']")
+            return
+        n_pages = pages.n_pages
+        for name, leaf in pages.items():
+            if name not in pages.tails:
+                c.err("PC1", f"pool leaf {name!r} has no per-token shape",
                       f"['pages']['{name}']")
                 return
-            if h != first:
-                c.err("PC1", f"pool leaves disagree on (stack, n_pages, "
-                             f"page_size): {heads}",
+            fmt = pages.fmt(name)
+            want = fmt.shape(tshape[0], n_pages)
+            if _shape(leaf) != want:
+                c.err("PC1", f"pool leaf {_shape(leaf)} is not the "
+                             f"lane-dense storage {want} (stack, blocks, "
+                             f"rows, lanes) of {n_pages} pages of "
+                             f"{pages.page} tokens of "
+                             f"{pages.tails[name]} values",
                       f"['pages']['{name}']")
-        n_pages = first[1]
-        if tshape[0] != first[0]:
-            c.err("PC1", f"table stack dim {tshape[0]} != pool stack dim "
-                         f"{first[0]}", "['table']")
+                return
         quantized = any(_dtype(v) in ("int8", "uint8")
                         for k, v in pages.items()
                         if k in ("k", "v", "ckv", "kpe"))
@@ -362,12 +370,11 @@ def _walk_paged(cache, path, findings: List[Finding],
             for name in ("k_scale", "v_scale"):
                 if name not in pages:
                     continue
-                want = _shape(pages[name[0]])[:4]
-                if _shape(pages[name]) != want:
-                    c.err("PA1", f"scale leaf shape {_shape(pages[name])} "
-                                 f"!= payload (stack, n_pages, page, KV) "
-                                 f"prefix {want}",
-                          f"['pages']['{name}']")
+                want = pages.tails[name[0]][:1]
+                if pages.tails[name] != want:
+                    c.err("PA1", f"scale leaf holds {pages.tails[name]} "
+                                 f"values a token, not one per KV head "
+                                 f"{want}", f"['pages']['{name}']")
                 if _dtype(pages[name]) not in _FLOATS:
                     c.err("PA1", f"per-token scale must be float32, got "
                                  f"{_dtype(pages[name])}",

@@ -35,8 +35,9 @@ decode-attention backend (``models.attention.paged_attn_backend``):
   ``gather``/``ref`` and for prefill, where the gather read side is the
   design.
 * ``kv-full-width-gather`` — a ``gather`` materializes the contiguous
-  (B, nb, page, ...) view of a paged pool leaf (quantized or float):
-  the O(max_len) ``paged_gather`` the fused kernel exists to delete.
+  (B, nb, rows, lanes) view of a paged pool leaf's stored pages
+  (quantized or float): the O(max_len) ``kv_pages.read_pages`` the
+  fused kernel exists to delete.
   Same severity policy.
 * ``kv-clean`` — fused decode saw KV payloads and materialized neither
   (the footprint census ``benchmarks/decode_bench.py`` asserts on).
@@ -318,7 +319,8 @@ class KVLeaf:
     paged: bool
     kv: int                   # KV heads
     dh: int                   # dequantized head dim (2x stored for int4)
-    tail3: tuple              # stored trailing dims (page|T, KV, dh_s)
+    span: int                 # tokens a read covers at least: page | T
+    block: tuple              # a paged leaf's stored (rows, lanes) page
 
 
 def _path_keys(path) -> List[Optional[str]]:
@@ -328,8 +330,20 @@ def _path_keys(path) -> List[Optional[str]]:
 def _kv_payload_invars(jaxpr, args: tuple) -> Dict:
     """Map jaxpr invars to the KVLeaf they carry (cache ``k``/``v``).
 
-    Contiguous float caches are skipped: their in-place write is an
-    unavoidable full-width float op, so they cannot be lint sources."""
+    A paged leaf's heads and head dim come from its ``PagePool``'s
+    per-token shapes (the stored rows are lane-dense); a contiguous
+    leaf's from its (…, T, KV, dh) shape.  Contiguous float caches are
+    skipped: their in-place write is an unavoidable full-width float op,
+    so they cannot be lint sources."""
+    from ..kernels.kv_pages import PagePool
+    pools = {}
+    for path, node in jax.tree_util.tree_flatten_with_path(
+            args, is_leaf=lambda x: isinstance(x, PagePool))[0]:
+        if isinstance(node, PagePool):
+            for name in _KV_FIELDS:
+                if name in node:
+                    key = path + (jax.tree_util.DictKey(name),)
+                    pools[jax.tree_util.keystr(key)] = (node, name)
     flat, _ = jax.tree_util.tree_flatten_with_path(args)
     invars = jaxpr.jaxpr.invars
     if len(flat) != len(invars):
@@ -344,14 +358,21 @@ def _kv_payload_invars(jaxpr, args: tuple) -> Dict:
             continue                             # recurrent state rows
         dt = jnp.dtype(leaf.dtype)
         bits = {jnp.dtype(jnp.int8): 8, jnp.dtype(jnp.uint8): 4}.get(dt, 32)
-        paged = "pages" in keys
+        label = jax.tree_util.keystr(path)
+        paged = label in pools
         if bits == 32 and (not paged
                            or not jnp.issubdtype(dt, jnp.floating)):
             continue
-        dh = shape[-1] * 2 if bits == 4 else shape[-1]
-        payload[var] = KVLeaf(path=jax.tree_util.keystr(path), bits=bits,
-                              paged=paged, kv=shape[-2], dh=dh,
-                              tail3=shape[-3:])
+        if paged:
+            pool, name = pools[label]
+            kv, dh_s = pool.tails[name]
+            span, block = pool.page, shape[-2:]
+        else:
+            kv, dh_s = shape[-2:]
+            span, block = shape[-3], ()
+        payload[var] = KVLeaf(path=label, bits=bits, paged=paged, kv=kv,
+                              dh=dh_s * 2 if bits == 4 else dh_s,
+                              span=span, block=tuple(block))
     return payload
 
 
@@ -410,8 +431,8 @@ class _KVWalk:
                     leaf = payload[v]
                     for ov in eqn.outvars:
                         osh = tuple(ov.aval.shape)
-                        if (len(osh) == len(v.aval.shape) + 1
-                                and osh[-3:] == tuple(leaf.tail3)):
+                        # whole stored pages, one a (slot, block) entry
+                        if len(osh) >= 4 and osh[-2:] == leaf.block:
                             self._emit(
                                 leaf, "kv-full-width-gather",
                                 "sanctioned-kv-gather",
@@ -439,7 +460,7 @@ class _KVWalk:
                     continue
                 for leaf in out_taint:
                     if osh[-2:] == (leaf.kv, leaf.dh) \
-                            and osh[-3] >= leaf.tail3[0]:
+                            and osh[-3] >= leaf.span:
                         self._emit(
                             leaf, "kv-dequant-materialization",
                             "sanctioned-kv-dequant",

@@ -42,6 +42,8 @@ from typing import Any, List, Optional, Sequence, Tuple
 import jax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from ..kernels.kv_pages import PagePool
+
 # --------------------------------------------------------------------------
 # mesh context
 # --------------------------------------------------------------------------
@@ -102,6 +104,12 @@ def data_size(mesh=None) -> int:
     """Devices along the data-parallel axes of ``mesh`` (1 without one)."""
     mesh = mesh if mesh is not None else get_mesh()
     return 1 if mesh is None else _group_size(_batch_entry(mesh), mesh)
+
+
+def model_size(mesh=None) -> int:
+    """Devices along the 'model' axis of ``mesh`` (1 without one)."""
+    mesh = mesh if mesh is not None else get_mesh()
+    return 1 if mesh is None else dict(mesh.shape).get("model", 1)
 
 
 def rows_entry(n: int, mesh=None):
@@ -490,6 +498,35 @@ def run_kernel(kern, *args, in_specs, out_specs, psum_axis=None):
 # batch / cache rules
 # --------------------------------------------------------------------------
 
+def pool_pspec(pool: PagePool, name: str, pad: Optional[bool] = None,
+               label: Optional[str] = None) -> P:
+    """Placement of leaf ``name`` of a paged KV pool (stack, blocks,
+    rows, lanes): pages on the data axes, and lanes on 'model' where each
+    device's share is whole KV heads in whole lane tiles
+    (``PagePool.head_split``)."""
+    mesh = get_mesh()
+    if mesh is None:
+        return P()
+    dims: List[Any] = [None, _batch_entry(mesh), None, None]
+    if pool.head_split(name, model_size(mesh)):
+        dims[3] = "model"
+    return fit_spec(P(*dims), tuple(pool[name].shape), mesh,
+                    label=label or f"pages.{name}", pad=pad)
+
+
+def constrain_pool(pool: PagePool) -> PagePool:
+    """``pool`` held to its placement (:func:`pool_pspec`) inside a
+    program, so a step that writes it returns it as it was placed;
+    identity with no active mesh."""
+    mesh = get_mesh()
+    if mesh is None:
+        return pool
+    return pool.replace(**{
+        n: jax.lax.with_sharding_constraint(
+            leaf, NamedSharding(mesh, pool_pspec(pool, n, pad=False)))
+        for n, leaf in pool.items()})
+
+
 def batch_pspecs(batch) -> Any:
     """Shard dim 0 (the global batch) of every leaf across the data axes.
 
@@ -523,12 +560,20 @@ def cache_pspecs(state, batch_size: int, pad: Optional[bool] = None) -> Any:
     carry placement padding).
 
     Paged caches are recognized by path: pool leaves under ``'pages'``
-    (stack, P, page, KV, ...) shard their *page* axis on the data axes (the
-    paged analog of per-slot batch sharding — gathers/scatters through the
-    block table reshard as needed) and keep the rank>=5 KV-head rule;
-    ``'table'`` block tables are tiny int32 maps and stay replicated."""
+    (stack, blocks, rows, lanes; ``kernels.kv_pages``) shard their *page*
+    axis on the data axes (the paged analog of per-slot batch sharding —
+    gathers/scatters through the block table reshard as needed), and
+    their lanes on 'model' where each device's share is whole KV heads
+    in whole lane tiles (``PagePool.head_split``: the fused decode
+    kernel then reads its own heads' lanes, ``attention._fused``); the
+    per-token scales stay whole.  ``'table'`` block tables are tiny
+    int32 maps and stay replicated."""
     mesh = get_mesh()
     flat, treedef = jax.tree_util.tree_flatten_with_path(state)
+    is_pool = lambda x: isinstance(x, PagePool)  # noqa: E731
+    pools = {jax.tree_util.keystr(path): x for path, x in
+             jax.tree_util.tree_flatten_with_path(state, is_leaf=is_pool)[0]
+             if is_pool(x)}
 
     def _keys(path) -> List[str]:
         return [k.key for k in path
@@ -544,10 +589,8 @@ def cache_pspecs(state, batch_size: int, pad: Optional[bool] = None) -> Any:
             return P(*dims)
         label = jax.tree_util.keystr(path)
         if "pages" in keys:
-            dims[1] = _batch_entry(mesh)
-            if len(shape) >= 5:
-                dims[-2] = "model"
-            return fit_spec(P(*dims), shape, mesh, label=label, pad=pad)
+            return pool_pspec(pools[jax.tree_util.keystr(path[:-1])],
+                              keys[-1], pad=pad, label=label)
         # rank>=4 leaves are stacked (L, B, ...): dim 0 is the layer axis,
         # so never batch-shard it even when n_layers == batch_size.
         start = 1 if len(shape) >= 4 else 0

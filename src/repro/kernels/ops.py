@@ -59,14 +59,15 @@ lint"):
 * ``BP3`` — ``scale`` LUT is f32 and finite (it pre-folds /(2^n - 1)
   and each block's power-of-two container rescale, so a NaN/inf here
   silently poisons every dequant).
-* ``PC1``-``PC3`` — paged decode caches: pool leaves agree on
-  (stack, n_pages, page_size), block tables are integer
+* ``PC1``-``PC3`` — paged decode caches: the pool is a
+  ``kv_pages.PagePool`` whose leaves have the lane-dense page storage of
+  their per-token shapes, block tables are integer
   (stack, n_slots, nb) with every id inside the pool (``PC2`` flags
   orphaned ids and un-refcounted page sharing), and quantized pools
   carry their per-token scale leaves.
 * ``PA1``-``PA3`` — fused paged-attention invariants: k/v pools agree
-  on dtype/shape and carry float32 scales matching the payload's
-  (stack, n_pages, page, KV) prefix (``PA1``); the pool holds the
+  on dtype/shape and carry float32 scales, one per token and KV head
+  (``PA1``); the pool holds the
   reserved trash page 0 plus >= 1 allocatable page and >= 1 block per
   slot row (``PA2``); a slot's live pages are a contiguous prefix of
   its table row — the kernel walks blocks in order and the fill level

@@ -110,28 +110,31 @@ def _unpack_nibbles(u: jnp.ndarray) -> jnp.ndarray:
 
 
 def paged_attention_ref(q, k_pages, v_pages, k_scale, v_scale, table,
-                        kv_len, *, window=None,
+                        kv_len, *, page: int, layer=0, window=None,
                         softcap: float = 0.0) -> jnp.ndarray:
     """Gather-then-softmax oracle for ``kernels.paged_attention``.
 
-    Same contract as the kernel (q (B, KV, G, dh); pool leaves
-    (P, page, KV, dh|dh//2); per-token scales or None; (B, nb) table;
-    (B,) fill levels) — but it materializes the contiguous (B, T, ...)
-    view and the f32 KV tree the kernel exists to avoid, so it is the
-    allclose target, never the hot path."""
+    Same contract as the kernel (q (B, KV, G, dh); stacked lane-dense
+    pool leaves, ``kernels.kv_pages``; per-token scales or None; (B, nb)
+    table; (B,) fill levels) — but it materializes the contiguous
+    (B, T, ...) view and the f32 KV tree the kernel exists to avoid, so
+    it is the allclose target, never the hot path."""
+    from .kv_pages import PageFormat, read_pages
     b, kv, g, dh = q.shape
     neg_inf = -2.0e38
+    dh_s = dh // 2 if k_pages.dtype == jnp.uint8 else dh
 
-    def gather(leaf):
-        x = jnp.take(leaf, table, axis=0)
-        return x.reshape(b, -1, *leaf.shape[2:])     # (B, nb*page, ...)
+    def gather(leaf, tail):
+        x = read_pages(leaf, PageFormat(page, kv * tail), layer, table)
+        return x.reshape(b, -1, kv, tail) if tail > 1 else \
+            x.reshape(b, -1, kv)
 
-    k, v = gather(k_pages), gather(v_pages)
+    k, v = gather(k_pages, dh_s), gather(v_pages, dh_s)
     if k.dtype == jnp.uint8:                         # nibble-packed int4
         k, v = _unpack_nibbles(k), _unpack_nibbles(v)
     if k_scale is not None:
-        k = k.astype(jnp.float32) * gather(k_scale)[..., None]
-        v = v.astype(jnp.float32) * gather(v_scale)[..., None]
+        k = k.astype(jnp.float32) * gather(k_scale, 1)[..., None]
+        v = v.astype(jnp.float32) * gather(v_scale, 1)[..., None]
     else:
         k, v = k.astype(jnp.float32), v.astype(jnp.float32)
     t = k.shape[1]
@@ -151,18 +154,22 @@ def paged_attention_ref(q, k_pages, v_pages, k_scale, v_scale, table,
 
 
 def latent_attention_ref(q_lat, q_pe, ckv, ckv_scale, kpe, kpe_scale,
-                         table, kv_len, *, sm_scale: float) -> jnp.ndarray:
+                         table, kv_len, *, page: int, sm_scale: float,
+                         layer=0) -> jnp.ndarray:
     """Gather-then-softmax oracle for ``kernels.latent_attention``: the
     same contract, materializing each row's dequantized (B, T, r) latent
     and (B, T, dr) rope keys."""
-    b = q_lat.shape[0]
+    from .kv_pages import PageFormat, read_pages
+    r, dr = q_lat.shape[-1], q_pe.shape[-1]
 
-    def gather(leaf):
-        x = jnp.take(leaf, table, axis=0)
-        return x.reshape(b, -1, *leaf.shape[2:]).astype(jnp.float32)
+    def gather(leaf, width):
+        return read_pages(leaf, PageFormat(page, width), layer,
+                          table).astype(jnp.float32)
 
-    c = gather(ckv) * gather(ckv_scale)[..., None]
-    pe = gather(kpe) * gather(kpe_scale)[..., None]
+    c, pe = gather(ckv, r), gather(kpe, dr)
+    if ckv_scale is not None:
+        c = c * gather(ckv_scale, 1)
+        pe = pe * gather(kpe_scale, 1)
     s = (jnp.einsum("bhr,btr->bht", q_lat.astype(jnp.float32), c,
                     preferred_element_type=jnp.float32)
          + jnp.einsum("bhd,btd->bht", q_pe.astype(jnp.float32), pe,

@@ -4,6 +4,7 @@ annotated with logical axes (heads on the 'model' mesh axis)."""
 from __future__ import annotations
 
 import contextlib
+import math
 from typing import Dict, Optional, Tuple
 
 import jax
@@ -11,7 +12,10 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ..core.quantize import pack_int4, unpack_int4
-from ..dist.sharding import constraint, data_size, get_mesh, run_kernel
+from ..dist.sharding import (constrain_pool, constraint, data_size,
+                              get_mesh, model_size, run_kernel)
+from ..kernels.kv_pages import (PageFormat, PagePool, read_pages, to_rows,
+                                write_tokens)
 from ..kernels.paged_attention import paged_attention
 from ..kernels.pallas_utils import fit_block
 from ..kernels.ref import paged_attention_ref
@@ -102,32 +106,41 @@ def dequantize_kv(qx: jnp.ndarray, scale: jnp.ndarray, dtype) -> jnp.ndarray:
     return w.astype(dtype) * scale[..., None].astype(dtype)
 
 
-def _cache_write(buf, update, idx, axis: int = 1):
-    """Write ``update`` into ``buf`` at offset ``idx`` along ``axis``.
+def _cache_write(buf, update, idx, layer):
+    """Write ``update`` (B, S, ...) into layer ``layer`` of a stacked
+    contiguous leaf (stack, B, T, ...) at offset ``idx`` along T.
 
     Scalar ``idx`` writes every batch row at the same offset (legacy
     whole-batch decode); a (B,) vector writes each row at its own offset
-    (continuous batching: dim 0 of both operands is the batch/slot dim)."""
+    (continuous batching).  Offsets clamp so the update fits, as
+    ``dynamic_update_slice`` does."""
+    update = update.astype(buf.dtype)
+    zeros = (0,) * (buf.ndim - 3)
     if jnp.ndim(idx) == 1:
         return jax.vmap(
-            lambda b, u, i: jax.lax.dynamic_update_slice_in_dim(
-                b, u, i, axis=axis - 1))(buf, update, idx)
-    return jax.lax.dynamic_update_slice_in_dim(buf, update, idx, axis=axis)
+            lambda b, u, i: jax.lax.dynamic_update_slice(
+                b, u[None], (layer, i) + zeros),
+            in_axes=(1, 0, 0), out_axes=1)(buf, update, idx)
+    return jax.lax.dynamic_update_slice(buf, update[None],
+                                        (layer, 0, idx) + zeros)
 
 
 # ---------------------------------------------------------------------------
 # paged cache (block-granular pool + per-slot block tables)
 # ---------------------------------------------------------------------------
 #
-# A paged layer cache is ``{"pages": {k, v[, k_scale, v_scale]}, "table"}``:
-# pool leaves carry a global page axis (P, page, KV, ...) instead of the
-# per-slot (B, T, ...) layout, and ``table`` (B, nb) maps each slot's
-# block b to the pool page holding its tokens [b*page, (b+1)*page).  Page 0
-# is reserved as the trash page: parked slots and unallocated table entries
-# point at it, and everything routed there stays masked by the per-slot
-# fill level.  The storage format (int8 / nibble-packed int4 + per-token
-# scales) is identical to the contiguous cache — paging changes residency,
-# not representation.
+# A paged cache is ``{"pages": PagePool, "table"}`` for the whole layer
+# stack: pool leaves hold every layer's pages in the lane-dense storage of
+# ``kernels.kv_pages`` — (stack, blocks, rows, lanes), a token's values
+# flattened into its slot of a row — and ``table`` (stack, B, nb) maps
+# each slot's block b to the pool page holding its tokens [b*page,
+# (b+1)*page).  Readers and writers take the stack and a layer index and
+# touch only that layer's pages: no layer is sliced out of the stack or
+# written back.  Page 0 is reserved as the trash page: parked slots and
+# unallocated table entries point at it, and everything routed there
+# stays masked by the per-slot fill level.  The values (int8 /
+# nibble-packed int4 + per-token scales) are the contiguous cache's —
+# paging changes residency and layout, not representation.
 
 def page_coords(table, idx, seq: int, page: int):
     """Slot-relative write positions -> (pool page ids, in-page offsets).
@@ -144,24 +157,17 @@ def page_coords(table, idx, seq: int, page: int):
     return pids, pos % page
 
 
-def paged_gather(pool_leaf, table):
-    """(P, page, ...) pool leaf + (B, nb) table -> contiguous (B, T, ...)
-    per-slot view (T = nb * page), token order preserved."""
-    g = jnp.take(pool_leaf, table, axis=0)
-    return g.reshape(g.shape[0], g.shape[1] * g.shape[2], *g.shape[3:])
-
-
 # ---------------------------------------------------------------------------
 # decode-attention dispatch (mirrors models.common.matmul_backend)
 # ---------------------------------------------------------------------------
 #
-# ``gather`` is the legacy read side above (paged_gather / _cache_write +
-# in-graph dequant + attention_core); ``fused`` walks the block table
-# inside the Pallas kernel so neither the contiguous (B, T, ...) KV view
-# nor the f32 KV tree is ever materialized; ``ref`` is the kernel's
-# pure-jnp oracle.  Both non-gather backends are decode-only (one query
-# token, causal self-attention) — every other shape falls back to gather
-# in-trace, which the graph lint flags under a fused engine.
+# ``gather`` is the legacy read side (``_gather_read``: each slot's pages
+# gathered in token order + in-graph dequant + attention_core); ``fused``
+# walks the block table inside the Pallas kernel so neither the contiguous
+# (B, T, ...) KV view nor the f32 KV tree is ever materialized; ``ref`` is
+# the kernel's pure-jnp oracle.  Both non-gather backends are decode-only
+# (one query token, causal self-attention) — every other shape falls back
+# to gather in-trace, which the graph lint flags under a fused engine.
 
 PAGED_ATTN_BACKENDS = ("gather", "fused", "ref")
 _PA_BACKEND_STACK = ["gather"]
@@ -185,75 +191,97 @@ def current_paged_attn_backend() -> str:
     return _PA_BACKEND_STACK[-1]
 
 
-def paged_attn(q, store: Dict, table, kv_len, *, window=0,
+def paged_attn(q, store: PagePool, table, kv_len, *, layer=0, window=0,
                attn_softcap: float = 0.0, trash_page: bool = True,
                backend: Optional[str] = None) -> jnp.ndarray:
     """One decode step of attention straight over a (quantized) page pool.
 
-    q: (B, 1, H, dh) roped queries; ``store``: pool leaves
-    ``{"k", "v"[, "k_scale", "v_scale"]}`` (P, page, KV, ...);
-    ``table``: (B, nb); ``kv_len``: (B,) fill levels *including* the
-    token just written.  Returns (B, 1, H, dh) in q's dtype.  The
+    q: (B, 1, H, dh) roped queries; ``store``: the stacked pool
+    ``{"k", "v"[, "k_scale", "v_scale"]}`` and ``layer`` its layer to
+    read; ``table``: (B, nb); ``kv_len``: (B,) fill levels *including*
+    the token just written.  Returns (B, 1, H, dh) in q's dtype.  The
     contiguous cache is served through the same entry by viewing each
     slot's (T, ...) row as pages with ``trash_page=False``: its page 0
     holds data, so the fused kernel bounds a row by ``kv_len`` alone
     (see ``_decode_read``)."""
     backend = backend or current_paged_attn_backend()
     b, s, h, dh = q.shape
-    kv = store["k"].shape[2]
+    kv = store.tails["k"][0]
     qg = q.reshape(b, kv, h // kv, dh)
     win = None if isinstance(window, int) and window == 0 else \
         jnp.asarray(window, jnp.int32)
     args = (qg, store["k"], store["v"], store.get("k_scale"),
             store.get("v_scale"), table, kv_len)
+    kw = dict(page=store.page, layer=layer, window=win, softcap=attn_softcap)
     if backend == "fused":
-        out = _fused_over_heads(args, win, attn_softcap, trash_page)
+        out = _fused(args, kw, trash_page,
+                     store.head_split("k", model_size()))
     elif backend == "ref":
-        out = paged_attention_ref(*args, window=win, softcap=attn_softcap)
+        out = paged_attention_ref(*args, **kw)
     else:
         raise ValueError(f"paged_attn executes 'fused' or 'ref', "
                          f"got {backend!r}")
     return out.reshape(b, s, h, dh).astype(q.dtype)
 
 
-def _fused_over_heads(args, win, attn_softcap, trash_page):
-    """The paged-attention kernel, each device taking its share of the KV
-    heads when the mesh's 'model' axis divides them (the decode state's
-    layout, ``cache_pspecs``); otherwise every device takes all heads.
+def _fused(args, kw, trash_page, split: bool):
+    """The paged-attention kernel under the active mesh.  With ``split``
+    (the pool's K/V lanes sharded on 'model', ``PagePool.head_split``)
+    each device takes its share of the KV heads: its queries, its lanes
+    of the pool and, from the whole scale leaves, its heads' scales.
+    Otherwise every device takes every head.
 
     Meshes with data axes wider than 1 are refused: they shard the page
     pool, and block tables hold global page ids, so no device could read
     its slots' pages alone."""
-    q, k, v, k_scale, v_scale, table, kv_len = args
     mesh = get_mesh()
     if data_size(mesh) > 1:
         raise NotImplementedError(
-            f"the fused paged-attention kernel runs on meshes of data "
+            f"fused paged attention runs on meshes with data "
             f"size 1 only (this mesh: {dict(mesh.shape)}); the page pool "
             f"is sharded over the data axes and block tables hold global "
             f"page ids.  Use attn_backend='gather' on this mesh.")
-    m = dict(mesh.shape).get("model", 1) if mesh is not None else 1
-    heads = "model" if m > 1 and q.shape[1] % m == 0 else None
-    quant = k_scale is not None
-    ops = [q, k, v] + ([k_scale, v_scale] if quant else []) \
-        + [table, kv_len] + ([] if win is None else [win])
-    specs = [P(None, heads)] + [P(None, None, heads)] * (4 if quant else 2)
-    specs += [P()] * (len(ops) - len(specs))
+    ops = [a for a in args if a is not None] + [kw["layer"]] + \
+        ([] if kw["window"] is None else [kw["window"]])
+    quant = args[3] is not None
+    kv = args[0].shape[1]
+    heads = "model" if split else None
+    specs = [P(None, heads)] + [P(None, None, None, heads)] * 2 \
+        + [P()] * (len(ops) - 3)
 
     def kern(q, k, v, *rest):
         ks, vs, rest = (rest[0], rest[1], rest[2:]) if quant \
             else (None, None, rest)
-        return paged_attention(q, k, v, ks, vs, rest[0], rest[1],
-                               window=rest[2] if len(rest) > 2 else None,
-                               softcap=attn_softcap, trash_page=trash_page)
-    return run_kernel(kern, *ops, in_specs=specs, out_specs=P(None, heads))
+        table, kv_len, layer = rest[:3]
+        head0 = jax.lax.axis_index("model") * q.shape[1] if split else 0
+        return paged_attention(q, k, v, ks, vs, table, kv_len,
+                               page=kw["page"], layer=layer,
+                               window=rest[3] if len(rest) > 3 else None,
+                               softcap=kw["softcap"], trash_page=trash_page,
+                               head0=head0, scale_heads=kv)
+    return run_kernel(kern, *ops, in_specs=specs,
+                      out_specs=P(None, heads))
 
 
-def _as_pool(leaf, page: int):
-    """(B, T, ...) contiguous cache leaf -> (B*(T//page), page, ...) pool
-    view (a free row-major reshape — paging as a *view*, not a copy)."""
-    b, t = leaf.shape[0], leaf.shape[1]
-    return leaf.reshape(b * (t // page), page, *leaf.shape[2:])
+def identity_pool(cache: Dict, layer):
+    """Layer ``layer`` of a contiguous (stack, B, T, ...) cache as a
+    one-layer page pool read through an identity block table: each
+    slot's (T, ...) row becomes T // page pages (no trash page).  The
+    re-layout is a copy of the layer, so it runs under ``kv_slice``."""
+    with jax.named_scope("kv_slice"):
+        b, t = next(iter(cache.values())).shape[1:3]
+        page = fit_block(min(128, t), t, 1)
+        tails = {n: leaf.shape[3:] for n, leaf in cache.items()}
+        leaves = {}
+        for n, tail in tails.items():
+            x = jax.lax.dynamic_index_in_dim(cache[n], layer, 0,
+                                             keepdims=False)
+            w = math.prod(tail)
+            leaves[n] = to_rows(x.reshape(1, b * (t // page), page, w),
+                                PageFormat(page, w))
+        ident = jnp.arange(b * (t // page), dtype=jnp.int32).reshape(
+            b, t // page)
+        return PagePool(leaves, page, b * (t // page), tails), ident
 
 
 def _mask_for(q_pos, kv_pos, causal, window, kv_len):
@@ -385,11 +413,14 @@ def attn_forward(p: Dict, x: jnp.ndarray, positions: jnp.ndarray, *,
                  kv_positions: Optional[jnp.ndarray] = None,
                  cache: Optional[Dict] = None,
                  cache_index: Optional[jnp.ndarray] = None,
-                 ) -> Tuple[jnp.ndarray, Optional[Dict]]:
+                 layer=0) -> Tuple[jnp.ndarray, Optional[Dict]]:
     """Full attention sub-layer: projections + rope + core + output proj.
 
-    With ``cache`` given, appends K/V at ``cache_index`` and attends over the
-    cache (decode / incremental prefill).  ``x_kv`` enables cross-attention.
+    With ``cache`` (a stacked cache; ``layer`` its layer for this
+    sub-layer) given, appends K/V at ``cache_index`` and attends over
+    the cache (decode / incremental prefill); the stacked cache comes
+    back with only this layer's new tokens written.  ``x_kv`` enables
+    cross-attention.
     """
     with jax.named_scope("attn_in"):
         q, k, v, q_pos, kv_pos = _project(
@@ -400,7 +431,7 @@ def attn_forward(p: Dict, x: jnp.ndarray, positions: jnp.ndarray, *,
     if cache is not None:
         with jax.named_scope("kv_write"):
             new_cache = _write_kv(cache, k, v, cache_index,
-                                  cache_bits(cache))
+                                  cache_bits(cache), layer)
     with jax.named_scope("attn_core"):
         if cache is not None:
             kv_len = jnp.broadcast_to(jnp.asarray(cache_index, jnp.int32)
@@ -412,9 +443,9 @@ def attn_forward(p: Dict, x: jnp.ndarray, positions: jnp.ndarray, *,
             if pa != "gather" and x.shape[1] == 1 and x_kv is None \
                     and causal:
                 out = _decode_read(q, new_cache, kv_len, window,
-                                   attn_softcap, pa)
+                                   attn_softcap, pa, layer)
             else:
-                k, v = _gather_read(new_cache, q.dtype)
+                k, v = _gather_read(new_cache, q.dtype, layer)
                 kv_pos = jnp.broadcast_to(jnp.arange(k.shape[1])[None, :],
                                           (x.shape[0], k.shape[1]))
         if out is None:
@@ -460,17 +491,16 @@ def _project(p: Dict, x, x_kv, positions, kv_positions, *, n_heads: int,
     return apply_rope(q, ang_q), apply_rope(k, ang_k), v, q_pos, kv_pos
 
 
-_KV_LEAVES = ("k", "v", "k_scale", "v_scale")
-
-
-def _write_kv(cache: Dict, k, v, idx, bits: int) -> Dict:
-    """The cache with the new K/V written at fill level ``idx``.
+def _write_kv(cache: Dict, k, v, idx, bits: int, layer) -> Dict:
+    """The stacked cache with layer ``layer``'s new K/V written at fill
+    level ``idx``.
 
     A quantized-at-rest cache (int8 / packed int4 with per-token/head
     dynamic scales) rounds each written position exactly once; reads
     dequantize in-graph, so HBM traffic drops 2x/4x at ~3% metadata
     overhead without compounding rounding error.  A paged cache
-    scatters the new tokens into their slots' pool pages."""
+    scatters the new tokens into their slots' pool pages of that layer
+    (``kv_pages.write_tokens``), in place under donation."""
     kq, vq = k, v
     scales = {}
     if bits < 32:
@@ -478,46 +508,53 @@ def _write_kv(cache: Dict, k, v, idx, bits: int) -> Dict:
         vq, scales["v_scale"] = quantize_kv(v, bits)
     new = {"k": kq, "v": vq, **scales}
     if "table" in cache:
-        store = cache["pages"]
-        pids, offs = page_coords(cache["table"], idx, k.shape[1],
-                                 store["k"].shape[1])
-        return dict(cache, pages=dict(store, **{
-            n: store[n].at[pids, offs].set(u) for n, u in new.items()}))
-    return dict(cache, **{n: _cache_write(cache[n], u, idx)
+        return write_pages(cache, new, idx, k.shape[1], layer)
+    return dict(cache, **{n: _cache_write(cache[n], u, idx, layer)
                           for n, u in new.items()})
 
 
+def write_pages(cache: Dict, new: Dict, idx, seq: int, layer) -> Dict:
+    """The paged cache with ``new`` (leaf name -> (B, ``seq``, ...)
+    values) written at fill level ``idx`` of layer ``layer``.  The pool
+    is held to its placement (``constrain_pool``) before and after the
+    write, so a sharded program's layer loop carries it as placed."""
+    pool = constrain_pool(cache["pages"])
+    pids, offs = page_coords(cache["table"][layer], idx, seq, pool.page)
+    return dict(cache, pages=constrain_pool(pool.replace(**{
+        n: write_tokens(pool[n], pool.fmt(n), layer, pids, offs, u)
+        for n, u in new.items()})))
+
+
 def _decode_read(q, cache: Dict, kv_len, window, attn_softcap: float,
-                 backend: str):
-    """One decode token's attention straight over the stored cache.  The
-    contiguous cache goes through the same kernel by viewing each slot's
-    (T, ...) row as T//page pages with an identity block table (a free
-    reshape, no trash page)."""
+                 backend: str, layer=0):
+    """One decode token's attention straight over layer ``layer`` of the
+    stored cache.  The contiguous cache goes through the same kernel as
+    an identity-table pool of its layer (:func:`identity_pool`)."""
     if "table" in cache:
-        return paged_attn(q, cache["pages"], cache["table"], kv_len,
-                          window=window, attn_softcap=attn_softcap,
-                          backend=backend)
-    b, t = cache["k"].shape[:2]
-    page = fit_block(min(128, t), t, 1)
-    pool = {n: _as_pool(cache[n], page) for n in _KV_LEAVES if n in cache}
-    ident = jnp.arange(b * (t // page), dtype=jnp.int32).reshape(
-        b, t // page)
+        return paged_attn(q, cache["pages"], cache["table"][layer], kv_len,
+                          layer=layer, window=window,
+                          attn_softcap=attn_softcap, backend=backend)
+    pool, ident = identity_pool(cache, layer)
     return paged_attn(q, pool, ident, kv_len, window=window,
                       attn_softcap=attn_softcap, trash_page=False,
                       backend=backend)
 
 
-def _gather_read(cache: Dict, dtype):
-    """Each slot's contiguous (B, T, KV, dh) K and V in ``dtype``; a paged
-    cache gathers the slot's block list back into token order, so
-    attention (and therefore decoding) is bit-identical to the contiguous
-    cache."""
+def _gather_read(cache: Dict, dtype, layer=0):
+    """Each slot's contiguous (B, T, KV, dh) K and V of layer ``layer``
+    in ``dtype``; a paged cache gathers the slot's pages in token order
+    (one gather a leaf, ``kv_pages.read_pages``), so attention (and
+    therefore decoding) is bit-identical to the contiguous cache."""
     if "table" in cache:
-        table = cache["table"]
-        store = {n: paged_gather(leaf, table)
-                 for n, leaf in cache["pages"].items()}
+        pool, table = cache["pages"], cache["table"][layer]
+        store = {n: read_pages(leaf, pool.fmt(n), layer, table).reshape(
+                     table.shape[0], -1, *pool.tails[n])
+                 for n, leaf in pool.items()}
     else:
-        store = cache
+        with jax.named_scope("kv_slice"):
+            store = {n: jax.lax.dynamic_index_in_dim(leaf, layer, 0,
+                                                     keepdims=False)
+                     for n, leaf in cache.items()}
     if "k_scale" in store:
         return (dequantize_kv(store["k"], store["k_scale"], dtype),
                 dequantize_kv(store["v"], store["v_scale"], dtype))

@@ -132,19 +132,13 @@ def decode(mp, cfg: ModelConfig, tokens, enc_out, cache=None, index=None):
     enc_pos = jnp.broadcast_to(
         jnp.arange(enc_out.shape[1])[None, :], (b, enc_out.shape[1]))
 
-    from .transformer import _index_cache, _update_cache
-
     def body(carry, lp):
         h, cache_c, li = carry
-        layer_cache = _index_cache(cache_c, li) if cache_c is not None \
-            else None
-        out, new_lc = attn_forward(
+        out, cache_c = attn_forward(
             lp["self_attn"], rms_norm(h, lp["ln_self"]), pos,
             n_heads=cfg.n_heads, n_kv=cfg.n_kv_heads, d_head=cfg.head_dim,
             rope_theta=cfg.rope_theta, causal=True,
-            cache=layer_cache, cache_index=index)
-        if cache_c is not None:
-            cache_c = _update_cache(cache_c, new_lc, li)
+            cache=cache_c, cache_index=index, layer=li)
         h = h + out
         out, _ = attn_forward(
             lp["cross_attn"], rms_norm(h, lp["ln_cross"]), pos,

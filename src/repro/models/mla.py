@@ -30,12 +30,12 @@ from typing import Dict, Optional
 import jax
 import jax.numpy as jnp
 
+from ..kernels.kv_pages import read_pages
 from ..kernels.latent_attention import latent_attention
-from ..kernels.pallas_utils import fit_block
 from ..kernels.ref import latent_attention_ref
 from .attention import (_cache_write, attention_core,
-                        current_paged_attn_backend, page_coords,
-                        paged_gather, quantize_kv)
+                        current_paged_attn_backend, identity_pool,
+                        quantize_kv, write_pages)
 from .common import make_weight, qdense, qmatmul, rms_norm
 from .rope import apply_rope, rope_angles, yarn_softmax_scale
 
@@ -79,9 +79,10 @@ def make_latent_cache(cfg, stack: int, batch: int, max_len: int,
             "kpe": jnp.zeros(shape + (dr,), dtype)}
 
 
-def _write(cache: Dict, c, k_pe, idx) -> Dict:
-    """The cache with this step's latents written at fill level ``idx``
-    (int8 rounds each once, with a scale per token and part)."""
+def _write(cache: Dict, c, k_pe, idx, layer) -> Dict:
+    """The stacked cache with layer ``layer``'s latents written at fill
+    level ``idx`` (int8 rounds each once, with a scale per token and
+    part); a paged cache scatters them into their pages in place."""
     store = cache.get("pages", cache)
     if "ckv_scale" in store:
         qc, sc = quantize_kv(c[:, :, None, :], 8)
@@ -92,60 +93,58 @@ def _write(cache: Dict, c, k_pe, idx) -> Dict:
         dt = store["ckv"].dtype
         new = {"ckv": c.astype(dt), "kpe": k_pe.astype(dt)}
     if "table" in cache:
-        pids, offs = page_coords(cache["table"], idx, c.shape[1],
-                                 store["ckv"].shape[1])
-        return dict(cache, pages=dict(store, **{
-            n: store[n].at[pids, offs].set(u) for n, u in new.items()}))
-    return dict(cache, **{n: _cache_write(cache[n], u, idx)
+        return write_pages(cache, new, idx, c.shape[1], layer)
+    return dict(cache, **{n: _cache_write(cache[n], u, idx, layer)
                           for n, u in new.items()})
 
 
-def _read(cache: Dict, dtype):
-    """Each slot's whole (B, T, r) latent and (B, T, dr) rope keys,
-    dequantized (a paged cache gathered back into token order)."""
-    store = cache
+def _read(cache: Dict, dtype, layer):
+    """Each slot's whole (B, T, r) latent and (B, T, dr) rope keys of
+    layer ``layer``, dequantized (a paged cache's pages gathered in token
+    order)."""
     if "table" in cache:
-        store = {n: paged_gather(leaf, cache["table"])
-                 for n, leaf in cache["pages"].items()}
+        pool, table = cache["pages"], cache["table"][layer]
+        store = {n: read_pages(leaf, pool.fmt(n), layer, table)
+                 for n, leaf in pool.items()}
+    else:
+        with jax.named_scope("kv_slice"):
+            store = {n: jax.lax.dynamic_index_in_dim(leaf, layer, 0,
+                                                     keepdims=False)
+                     for n, leaf in cache.items()}
     out = []
     for n in ("ckv", "kpe"):
         x = store[n].astype(jnp.float32)
         if f"{n}_scale" in store:
-            x = x * store[f"{n}_scale"][..., None]
+            x = x * store[f"{n}_scale"].reshape(x.shape[:-1])[..., None]
         out.append(x.astype(dtype))
     return out
 
 
 def _decode_read(q_lat, q_pe, cache: Dict, kv_len, sm_scale: float,
-                 backend: str):
-    """o_lat (B, H, r) of one decode token, read straight from the
-    stored latents; a contiguous cache is viewed as pages with an
-    identity table (a free reshape, no trash page), as in
-    ``attention._decode_read``."""
+                 backend: str, layer):
+    """o_lat (B, H, r) of one decode token, read straight from layer
+    ``layer``'s stored latents; a contiguous cache is read as an
+    identity-table pool of its layer, as in ``attention._decode_read``."""
     if "table" in cache:
-        store, table, trash = cache["pages"], cache["table"], True
+        store, table, trash = cache["pages"], cache["table"][layer], True
     else:
-        b, t = cache["ckv"].shape[:2]
-        page = fit_block(min(128, t), t, 1)
-        store = {n: x.reshape(b * (t // page), page, *x.shape[2:])
-                 for n, x in cache.items()}
-        table = jnp.arange(b * (t // page), dtype=jnp.int32).reshape(
-            b, t // page)
-        trash = False
-    ones = jnp.ones(store["ckv"].shape[:2], jnp.float32)
-    args = (q_lat, q_pe, store["ckv"], store.get("ckv_scale", ones),
-            store["kpe"], store.get("kpe_scale", ones), table, kv_len)
+        store, table = identity_pool(cache, layer)
+        layer, trash = 0, False
+    args = (q_lat, q_pe, store["ckv"], store.get("ckv_scale"),
+            store["kpe"], store.get("kpe_scale"), table, kv_len)
+    kw = dict(page=store.page, sm_scale=sm_scale, layer=layer)
     if backend == "fused":
-        return latent_attention(*args, sm_scale=sm_scale, trash_page=trash)
-    return latent_attention_ref(*args, sm_scale=sm_scale)
+        return latent_attention(*args, trash_page=trash, **kw)
+    return latent_attention_ref(*args, **kw)
 
 
 def mla_forward(p: Dict, x: jnp.ndarray, positions: jnp.ndarray, cfg,
                 cache: Optional[Dict] = None,
-                cache_index: Optional[jnp.ndarray] = None):
+                cache_index: Optional[jnp.ndarray] = None, layer=0):
     """MLA sub-layer on normed, quantized activations x (B, S, D): the
-    projections, the cache write and read, attention and ``W_o``.
-    Returns (out (B, S, D), new layer cache or None)."""
+    projections, the write and read of layer ``layer`` of the stacked
+    cache, attention and ``W_o``.  Returns (out (B, S, D), new stacked
+    cache or None)."""
     b, s, _ = x.shape
     h, r = cfg.n_heads, cfg.kv_lora_rank
     dn, dr, dv = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
@@ -170,7 +169,7 @@ def mla_forward(p: Dict, x: jnp.ndarray, positions: jnp.ndarray, cfg,
     new_cache = None
     if cache is not None:
         with jax.named_scope("kv_write"):
-            new_cache = _write(cache, c, k_pe, cache_index)
+            new_cache = _write(cache, c, k_pe, cache_index, layer)
     with jax.named_scope("attn_core"):
         if decode:
             kv_len = jnp.broadcast_to(
@@ -178,13 +177,13 @@ def mla_forward(p: Dict, x: jnp.ndarray, positions: jnp.ndarray, cfg,
             with jax.named_scope("latent_attn"):
                 o_lat = _decode_read(q_lat, q_pe[:, 0], new_cache, kv_len,
                                      sm_scale,
-                                     current_paged_attn_backend())
+                                     current_paged_attn_backend(), layer)
         else:
             kv_len = None
             if cache is None:
                 c_all, pe_all, kv_pos = c, k_pe, positions
             else:
-                c_all, pe_all = _read(new_cache, x.dtype)
+                c_all, pe_all = _read(new_cache, x.dtype, layer)
                 kv_pos = jnp.broadcast_to(
                     jnp.arange(c_all.shape[1])[None, :],
                     (b, c_all.shape[1]))

@@ -17,6 +17,7 @@ import jax.numpy as jnp
 
 from ..configs.base import ModelConfig
 from ..dist.sharding import constraint, shard_params_tree
+from ..kernels.kv_pages import make_pool
 from .attention import attn_forward, init_attn, is_kv_node
 from .common import (act_quant, embed_init, make_weight,
                      prepare_params, qmatmul, rms_norm, softcap)
@@ -33,13 +34,14 @@ from .ssm import init_mamba2, mamba2_forward, mamba2_init_state
 # metadata only: they change no fusion and cost nothing at run time.
 SCOPES = (
     "embed",       # token (and vision) embedding
-    "kv_slice",    # one layer's cache sliced out of the stacked cache
+    "kv_slice",    # a layer sliced out of a stacked contiguous cache or
+                   # recurrent state (a paged pool is read in place)
     "attn_in",     # pre-attention norm, activation quant, q/k/v, rope
     "kv_write",    # quantize the new K/V and write it into the cache
     "attn_core",   # read the cache and attend (paged kernel or gather)
     "latent_attn",  # MLA decode: latent scores and sum over the cache
     "attn_out",    # output projection, post-norm, residual
-    "kv_update",   # the layer's cache written back into the stack
+    "kv_update",   # a layer's recurrent state written back into the stack
     "mlp",         # pre-MLP norm, activation quant, MLP or experts
     "moe_route",   # router logits, softmax, top-k gates
     "moe_experts",  # the held routed experts' part of the output
@@ -190,7 +192,7 @@ def init_lm(key, cfg: ModelConfig) -> Dict:
 
 
 def _index_cache(cache, i):
-    """Slice layer i's cache out of stacked (L, ...) leaves."""
+    """Slice layer i's recurrent state out of stacked (L, ...) leaves."""
     with jax.named_scope("kv_slice"):
         return jax.tree_util.tree_map(
             lambda c: jax.lax.dynamic_index_in_dim(c, i, 0, keepdims=False),
@@ -209,7 +211,9 @@ def _update_cache(cache, new_layer, i):
 # ---------------------------------------------------------------------------
 
 def _attn_block(lp, h, positions, cfg: ModelConfig, is_local,
-                cache=None, index=None):
+                cache=None, index=None, layer=None):
+    """Attention sub-block over layer ``layer`` of the stacked ``cache``
+    (None without a cache); returns (h, the stacked cache)."""
     qc = cfg.quant
     with jax.named_scope("attn_in"):
         x = rms_norm(h, lp["ln_attn"])
@@ -218,7 +222,8 @@ def _attn_block(lp, h, positions, cfg: ModelConfig, is_local,
             cfg.alt_local_global else (cfg.sliding_window or 0)
     if cfg.kv_lora_rank:
         out, new_cache = mla_forward(lp["attn"], x, positions, cfg,
-                                     cache=cache, cache_index=index)
+                                     cache=cache, cache_index=index,
+                                     layer=layer)
         with jax.named_scope("attn_out"):
             return h + out, new_cache
     # window as traced value: attention uses dynamic comparison, so pass
@@ -227,7 +232,7 @@ def _attn_block(lp, h, positions, cfg: ModelConfig, is_local,
         lp["attn"], x, positions, n_heads=cfg.n_heads, n_kv=cfg.n_kv_heads,
         d_head=cfg.head_dim, rope_theta=cfg.rope_theta, causal=True,
         window=window, attn_softcap=cfg.attn_softcap, mrope=cfg.mrope,
-        cache=cache, cache_index=index)
+        cache=cache, cache_index=index, layer=layer)
     with jax.named_scope("attn_out"):
         if cfg.use_post_norms:
             out = rms_norm(out, lp["ln_attn_post"])
@@ -260,17 +265,15 @@ def _walk_dense(mp, cfg, h, positions, cache, index):
     dense layers (``dense_layers``) first, then ``layers``; the stacked
     cache holds every layer, in that order."""
     def body(carry, xs):
-        # cache rides in the carry and is updated in place per layer —
-        # scan carries alias buffers, so the KV cache is never duplicated
-        # (xs/ys threading would double-buffer multi-GiB caches).
+        # the stacked cache rides in the carry and each layer reads and
+        # writes its own pages of it in place (layer ``li``): no layer is
+        # sliced out or written back, and scan carries alias buffers, so
+        # the KV cache is never duplicated (xs/ys threading would
+        # double-buffer multi-GiB caches).
         h, aux, cache_c, li = carry
         lp, loc = xs
-        layer_cache = _index_cache(cache_c, li) if cache_c is not None \
-            else None
-        h, new_lc = _attn_block(lp, h, positions, cfg, loc,
-                                cache=layer_cache, index=index)
-        if cache_c is not None:
-            cache_c = _update_cache(cache_c, new_lc, li)
+        h, cache_c = _attn_block(lp, h, positions, cfg, loc,
+                                 cache=cache_c, index=index, layer=li)
         h, aux_l = _mlp_block(lp, h, cfg)
         h = constraint(h, "batch", None, None)
         return (h, aux + aux_l, cache_c, li + 1), None
@@ -354,17 +357,13 @@ def _walk_hybrid(mp, cfg, h, emb0, positions, cache, index):
         blk = xs
         (h, aux, mstates, li), _ = jax.lax.scan(
             mamba_body, (h, aux, mstates, li), blk)
-        attn_cache = _index_cache(acaches, si) if acaches is not None \
-            else None
         xcat = jnp.concatenate([h, emb0], axis=-1)
         xcat = rms_norm(xcat, mp["shared_ln"])
-        out, new_ac = attn_forward(
+        out, acaches = attn_forward(
             shared, xcat, positions, n_heads=cfg.n_heads,
             n_kv=cfg.n_kv_heads, d_head=cfg.head_dim,
             rope_theta=cfg.rope_theta, causal=True,
-            cache=attn_cache, cache_index=index)
-        if acaches is not None:
-            acaches = _update_cache(acaches, new_ac, si)
+            cache=acaches, cache_index=index, layer=si)
         h = h + out
         h = h + mlp_forward(mp["shared_mlp"],
                             rms_norm(h, mp["shared_ln2"]), cfg.mlp_kind)
@@ -485,20 +484,22 @@ def paginate_cache_tree(cache, n_slots: int, n_pages: int, page_size: int,
     """Zero *paged* decode cache mirroring contiguous ``cache``.
 
     Every KV sub-dict becomes ``{"pages", "table"}``: pool leaves trade the
-    per-slot (B, T) layout for a global (n_pages, page_size) page axis —
-    same storage dtypes, so int8/int4-at-rest formats carry over — and the
-    (stack, n_slots, nb) block table starts all-trash (page 0 is reserved;
-    a slot's block b maps to the pool page holding its tokens
-    [b*page_size, (b+1)*page_size)).  Recurrent leaves (no time axis) are
-    plain re-batched rows, as in :func:`rebatch_cache_tree`."""
+    per-slot (B, T) layout for ``n_pages`` pages of ``page_size`` tokens in
+    the lane-dense storage of ``kernels.kv_pages`` (a
+    :class:`~repro.kernels.kv_pages.PagePool`; same storage dtypes, so
+    int8/int4-at-rest formats carry over), and the (stack, n_slots, nb)
+    block table starts all-trash (page 0 is reserved; a slot's block b
+    maps to the pool page holding its tokens [b*page_size,
+    (b+1)*page_size)).  Recurrent leaves (no time axis) are plain
+    re-batched rows, as in :func:`rebatch_cache_tree`."""
+    def paged(node):
+        stack = next(iter(node.values())).shape[0]
+        return {"pages": make_pool({n: (l.shape[3:], l.dtype)
+                                    for n, l in node.items()},
+                                   stack, n_pages, page_size),
+                "table": jnp.zeros((stack, n_slots, nb), jnp.int32)}
     return _walk_cache_tree(
-        cache,
-        lambda node: {
-            "pages": {n: jnp.zeros((l.shape[0], n_pages, page_size,
-                                    *l.shape[3:]), l.dtype)
-                      for n, l in node.items()},
-            "table": jnp.zeros((next(iter(node.values())).shape[0],
-                                n_slots, nb), jnp.int32)},
+        cache, paged,
         lambda l: jnp.zeros((l.shape[0], n_slots, *l.shape[2:]), l.dtype))
 
 

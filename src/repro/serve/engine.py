@@ -69,6 +69,7 @@ warnings.filterwarnings(
 
 from ..dist.sharding import (batch_pspecs, cache_pspecs, get_mesh,
                              pad_leaf, param_pspecs, unpad_leaf, use_mesh)
+from ..kernels.kv_pages import put_pages, take_pages
 from ..models.api import ModelAPI
 from ..models.attention import PAGED_ATTN_BACKENDS, paged_attn_backend
 from ..models.common import MATMUL_BACKENDS, matmul_backend
@@ -439,9 +440,10 @@ class ServeEngine:
         def walk(cache, path):
             if isinstance(cache, dict):
                 if "table" in cache:
-                    for name, leaf in cache["pages"].items():
-                        rec["pages"][f"{path}.{name}"] = \
-                            np.asarray(leaf[:, ids])
+                    pool = cache["pages"]
+                    for name, leaf in pool.items():
+                        rec["pages"][f"{path}.{name}"] = np.asarray(
+                            take_pages(leaf, pool.fmt(name), ids))
                     return
                 for k, v in cache.items():
                     walk(v, f"{path}.{k}")
@@ -467,12 +469,13 @@ class ServeEngine:
         def walk(cache, path):
             if isinstance(cache, dict):
                 if "table" in cache:
-                    new = {name: (leaf.at[:, ids].set(
-                                      jnp.asarray(record["pages"]
-                                                  [f"{path}.{name}"]))
+                    pool = cache["pages"]
+                    new = {name: (put_pages(leaf, pool.fmt(name), ids,
+                                            record["pages"]
+                                            [f"{path}.{name}"])
                                   if record["n_pages"] else leaf)
-                           for name, leaf in cache["pages"].items()}
-                    return dict(cache, pages=new)
+                           for name, leaf in pool.items()}
+                    return dict(cache, pages=pool.replace(**new))
                 return {k: walk(v, f"{path}.{k}") for k, v in cache.items()}
             return cache.at[:, slot].set(jnp.asarray(record["rows"][path]))
 
@@ -489,9 +492,12 @@ class ServeEngine:
         def walk(cache):
             if isinstance(cache, dict):
                 if "table" in cache:
-                    return dict(cache, pages={
-                        name: leaf.at[:, dst].set(leaf[:, src])
-                        for name, leaf in cache["pages"].items()})
+                    pool = cache["pages"]
+                    return dict(cache, pages=pool.replace(**{
+                        name: put_pages(leaf, pool.fmt(name), [dst],
+                                        take_pages(leaf, pool.fmt(name),
+                                                   [src]))
+                        for name, leaf in pool.items()}))
                 return {k: walk(v) for k, v in cache.items()}
             return cache
         return dict(state, cache=walk(state["cache"]))

@@ -37,6 +37,7 @@ from repro.configs import REGISTRY
 from repro.dist.hlo_analysis import input_output_aliases, shape_census
 from repro.dist.sharding import (ShardingDropWarning, collect_spec_events,
                                  fit_spec)
+from repro.kernels.kv_pages import make_pool
 from repro.models import common as common_mod
 from repro.models.api import build
 from repro.models.common import (QuantConfig, make_weight, matmul_backend,
@@ -178,8 +179,8 @@ def test_engine_refuses_corrupt_tree(phi3, bitplane_params):
 # ---------------------------------------------------------------------------
 
 def _paged_state(table):
-    pages = {"k": np.zeros((1, 8, 4, 2, 3), np.float32),
-             "v": np.zeros((1, 8, 4, 2, 3), np.float32)}
+    pages = make_pool({"k": ((2, 3), np.float32), "v": ((2, 3), np.float32)},
+                      stack=1, n_pages=8, page=4)
     return {"cache": {"layer0": {"table": table, "pages": pages}}}
 
 
@@ -208,11 +209,30 @@ def test_shared_page_is_pc2_warning():
 
 
 def test_quantized_pool_needs_scales():
-    pages = {"k": np.zeros((1, 8, 4, 2, 3), np.int8),
-             "v": np.zeros((1, 8, 4, 2, 3), np.int8)}
+    pages = make_pool({"k": ((2, 3), np.int8), "v": ((2, 3), np.int8)},
+                      stack=1, n_pages=8, page=4)
     state = {"cache": {"l": {"table": np.zeros((1, 2, 4), np.int32),
                              "pages": pages}}}
     assert _errors(validate_decode_state(state, n_slots=2), "PC3")
+
+
+@pytest.mark.parametrize("pool", ["plain-dict", "page-minor-shape",
+                                  "wrong-stack"])
+def test_pool_must_be_lane_dense_pc1(pool):
+    """PC1 states the storage: a PagePool whose leaves are the lane-dense
+    (stack, blocks, rows, lanes) pages of their per-token shape."""
+    table = np.zeros((1, 2, 4), np.int32)
+    state = _paged_state(table)
+    good = state["cache"]["layer0"]["pages"]
+    if pool == "plain-dict":          # the pre-lane-dense (…, page, KV, dh)
+        bad = {n: np.zeros((1, 8, 4, 2, 3), np.float32) for n in good}
+    elif pool == "page-minor-shape":
+        bad = good.replace(k=np.zeros((1, 8, 4, 2, 3), np.float32))
+    else:
+        bad = good.replace(**{n: np.zeros((2,) + leaf.shape[1:], leaf.dtype)
+                              for n, leaf in good.items()})
+    state["cache"]["layer0"]["pages"] = bad
+    assert _errors(validate_decode_state(state, n_slots=2), "PC1")
 
 
 def test_wrong_slot_count_is_pc1():

@@ -20,9 +20,11 @@ import numpy as np
 import pytest
 
 from repro.configs import REGISTRY
+from repro.kernels.kv_pages import PageFormat, make_pool, to_rows
 from repro.kernels.latent_attention import latent_attention
 from repro.kernels.ref import latent_attention_ref
 from repro.models import mla
+from repro.models.attention import dequantize_kv, quantize_kv
 from repro.models.api import build
 from repro.models.common import QuantConfig
 from repro.models.moe import moe_forward
@@ -81,8 +83,7 @@ def _layer(cfg, bits=8):
     cfg = dataclasses.replace(cfg, kv_cache_bits=bits, quant=QuantConfig())
     p = jax.tree_util.tree_map(
         lambda a: a[0], mla.init_mla(jax.random.PRNGKey(3), cfg, 1))
-    cache = jax.tree_util.tree_map(
-        lambda a: a[0], mla.make_latent_cache(cfg, 1, 2, 32, jnp.float32))
+    cache = mla.make_latent_cache(cfg, 1, 2, 32, jnp.float32)  # 1 layer
     return cfg, p, cache
 
 
@@ -106,28 +107,107 @@ def test_absorbed_decode_equals_expanded(backend):
                                atol=2e-5 * scale, rtol=0)
 
 
+def _latent_pool(rng, n_pages, page, r, dr, stack=1, quant=True):
+    """Random stacked latent pool in the lane-dense storage the kernel
+    reads: int8 values with per-token scales, or float32 and None."""
+    def leaf(width, lo, hi, dtype):
+        x = (rng.integers(lo, hi, (stack, n_pages, page, width))
+             if dtype == jnp.int8 else
+             rng.uniform(lo, hi, (stack, n_pages, page, width)))
+        return to_rows(jnp.asarray(x, dtype), PageFormat(page, width))
+    if quant:
+        return (leaf(r, -127, 128, jnp.int8),
+                leaf(1, 0.001, 0.02, jnp.float32),
+                leaf(dr, -127, 128, jnp.int8),
+                leaf(1, 0.001, 0.02, jnp.float32))
+    return (leaf(r, -1, 1, jnp.float32), None,
+            leaf(dr, -1, 1, jnp.float32), None)
+
+
 def test_latent_kernel_matches_oracle():
     """The Pallas kernel (interpret mode) against its gather oracle on a
     paged int8 pool: rows of 0 (parked), part of one page and several
-    pages; a pool of 13 pages (not a multiple of the 8-page scale
-    block)."""
+    pages; a pool of 13 pages (not a multiple of the 8 pages whose
+    scales share a stored row)."""
     rng = np.random.default_rng(0)
     h, r, dr, page, n_pages = 4, 64, 16, 8, 13
-    ckv = jnp.asarray(rng.integers(-127, 128, (n_pages, page, r)), jnp.int8)
-    kpe = jnp.asarray(rng.integers(-127, 128, (n_pages, page, dr)),
-                      jnp.int8)
-    cs = jnp.asarray(rng.uniform(0.001, 0.02, (n_pages, page)), jnp.float32)
-    ps = jnp.asarray(rng.uniform(0.001, 0.02, (n_pages, page)), jnp.float32)
+    ckv, cs, kpe, ps = _latent_pool(rng, n_pages, page, r, dr)
     table = jnp.asarray([[0, 0, 0, 0], [3, 0, 0, 0], [5, 12, 1, 7]],
                         jnp.int32)
     kv_len = jnp.asarray([31, 5, 27], jnp.int32)
     ql = jnp.asarray(rng.normal(size=(3, h, r)), jnp.float32)
     qp = jnp.asarray(rng.normal(size=(3, h, dr)), jnp.float32)
     args = (ql, qp, ckv, cs, kpe, ps, table, kv_len)
-    got = latent_attention(*args, sm_scale=0.1)
-    want = latent_attention_ref(*args, sm_scale=0.1)
+    got = latent_attention(*args, page=page, sm_scale=0.1)
+    want = latent_attention_ref(*args, page=page, sm_scale=0.1)
     np.testing.assert_array_equal(got[0], 0.0)
     np.testing.assert_allclose(got[1:], want[1:], rtol=1e-5, atol=1e-6)
+
+
+# DeepSeek-V2-Lite's pages of 16 at its widths (r 512: one token a row;
+# dr 64: two a row; the scales: 8 pages a row) and the tiny config's
+@pytest.mark.parametrize("quant", [True, False], ids=["int8", "f32"])
+@pytest.mark.parametrize("h", [1, 4])
+@pytest.mark.parametrize("r,dr", [(512, 64), (64, 16)])
+def test_latent_kernel_reads_stacked_pool_at_layer(quant, h, r, dr):
+    """Rows of 1, 16, 129 and 320 tokens and a parked row (an all-trash
+    table) in a 3-layer stacked pool, read at layer 2."""
+    rng = np.random.default_rng(h + r)
+    page, nb, fills = 16, 20, (1, 16, 129, 320)
+    n_live = [-(-f // page) for f in fills]
+    n_pages = 1 + sum(n_live)
+    pool = _latent_pool(rng, n_pages, page, r, dr, stack=3, quant=quant)
+    ids = 1 + rng.permutation(n_pages - 1)
+    table = np.zeros((len(fills) + 1, nb), np.int32)
+    starts = np.cumsum([0] + n_live)
+    for row, n in enumerate(n_live):
+        table[row, :n] = ids[starts[row]:starts[row] + n]
+    kv_len = jnp.asarray(list(fills) + [nb * page], jnp.int32)
+    b = len(fills) + 1
+    ql = jnp.asarray(rng.normal(size=(b, h, r)), jnp.float32)
+    qp = jnp.asarray(rng.normal(size=(b, h, dr)), jnp.float32)
+    args = (ql, qp, pool[0], pool[1], pool[2], pool[3],
+            jnp.asarray(table), kv_len)
+    kw = dict(page=page, sm_scale=0.1, layer=2)
+    got = np.asarray(latent_attention(*args, **kw))
+    want = np.asarray(latent_attention_ref(*args, **kw))
+    np.testing.assert_array_equal(got[-1], 0.0)
+    np.testing.assert_allclose(got[:-1], want[:-1], rtol=1e-5, atol=1e-5)
+    other = np.asarray(latent_attention(*args, **dict(kw, layer=0)))
+    assert not np.allclose(other[:-1], got[:-1], atol=1e-3)
+
+
+@pytest.mark.parametrize("bits", [8, 16])
+@pytest.mark.parametrize("page", [8, 16])
+def test_latent_write_then_read_round_trip(bits, page):
+    """``mla._write`` scatters a layer's new latents into the stacked
+    lane-dense pool, ``mla._read`` returns them (int8: rounded once) at
+    their positions, and the other layers stay untouched."""
+    cfg = dataclasses.replace(CFG, kv_cache_bits=bits)
+    stack, layer, b, nb, seq = 3, 2, 2, 3, 6
+    contiguous = mla.make_latent_cache(cfg, stack, b, nb * page,
+                                       jnp.float32)
+    tails = {n: (l.shape[3:], l.dtype) for n, l in contiguous.items()}
+    table = jnp.asarray([[4, 1, 6], [2, 5, 3]], jnp.int32)
+    cache = {"pages": make_pool(tails, stack, 1 + b * nb, page),
+             "table": jnp.broadcast_to(table, (stack, b, nb))}
+    ks = jax.random.split(jax.random.PRNGKey(page), 2)
+    c = jax.random.normal(ks[0], (b, seq, cfg.kv_lora_rank))
+    pe = jax.random.normal(ks[1], (b, seq, cfg.qk_rope_dim))
+    idx = jnp.asarray([1, page + 2])
+    new = mla._write(cache, c, pe, idx, layer)
+    got_c, got_pe = mla._read(new, jnp.float32, layer)
+    want = [c, pe]
+    if bits == 8:
+        want = [dequantize_kv(*quantize_kv(x[:, :, None], 8),
+                              jnp.float32)[:, :, 0] for x in want]
+    for row, start in enumerate((1, page + 2)):
+        for g, w in zip((got_c, got_pe), want):
+            np.testing.assert_array_equal(
+                np.asarray(g[row, start:start + seq]), np.asarray(w[row]))
+    for other in (0, 1):
+        for leaf in new["pages"].values():
+            assert not np.asarray(leaf[other]).any()
 
 
 def _plain_moe(p, x, top_k, n_routed):

@@ -1,12 +1,15 @@
 """Fused paged-attention decode kernel: unit, oracle, lint, and parity.
 
-* ``page_coords`` / ``paged_gather`` edge cases — clamp-into-last-block
-  past the table end, trash-page (page 0) routing, (B,) vs scalar fill
-  levels — previously covered only indirectly through serving parity;
+* ``page_coords`` / ``kv_pages.read_pages`` edge cases —
+  clamp-into-last-block past the table end, trash-page (page 0) routing,
+  (B,) vs scalar fill levels — previously covered only indirectly
+  through serving parity;
 * kernel vs ``paged_attention_ref`` allclose across kv-bits, GQA ratios,
   ragged fill levels, sliding windows, softcap, and ``block_kv`` tiles;
   fills at and around page and multi-page step boundaries, a parked row
-  (an all-trash table), and the contiguous cache's identity view;
+  (an all-trash table), and the contiguous cache's identity view; the
+  stacked lane-dense pool read at a layer other than 0, and the cache
+  write then read back on that storage;
 * graph-lint footprint census: the fused decode jaxpr holds neither a
   full-width KV gather nor an f32 KV materialization (``kv-clean``), and
   a forced gather fallback under a fused engine is an ERROR;
@@ -20,12 +23,15 @@ import numpy as np
 import pytest
 
 from repro.configs import REGISTRY
+from repro.kernels.kv_pages import (PageFormat, make_pool, read_pages,
+                                    to_rows)
 from repro.kernels.paged_attention import paged_attention
 from repro.kernels.ref import paged_attention_ref
 from repro.models.api import build
 from repro.models.attention import (PAGED_ATTN_BACKENDS, _decode_read,
+                                    _gather_read, _write_kv, dequantize_kv,
                                     page_coords, paged_attn_backend,
-                                    paged_gather, quantize_kv)
+                                    quantize_kv)
 from repro.models.common import QuantConfig
 from repro.serve import Request, SamplingParams, ServeEngine
 
@@ -76,34 +82,55 @@ def test_page_coords_trash_page_for_unallocated_blocks():
 
 
 def test_paged_gather_layout_and_trash_masking():
-    pool = jnp.arange(5 * 2 * 3, dtype=jnp.float32).reshape(5, 2, 3)
+    # 5 pages of 2 tokens of 3 values, stored lane-dense (a row of 128
+    # lanes holds 32 tokens: 16 pages share one row block)
+    vals = jnp.arange(5 * 2 * 3, dtype=jnp.float32).reshape(1, 5, 2, 3)
+    fmt = PageFormat(2, 3)
+    pool = to_rows(vals, fmt)
+    assert pool.shape == (1, 1, 1, 128)
     table = jnp.asarray([[2, 0], [4, 1]], jnp.int32)
-    out = paged_gather(pool, table)                      # (B, nb*page, 3)
+    out = read_pages(pool, fmt, 0, table)                # (B, nb*page, 3)
     assert out.shape == (2, 4, 3)
     np.testing.assert_array_equal(np.asarray(out[0, :2]),
-                                  np.asarray(pool[2]))
+                                  np.asarray(vals[0, 2]))
     np.testing.assert_array_equal(np.asarray(out[0, 2:]),
-                                  np.asarray(pool[0]))  # trash page content
+                                  np.asarray(vals[0, 0]))  # trash page
     np.testing.assert_array_equal(np.asarray(out[1, 2:]),
-                                  np.asarray(pool[1]))
+                                  np.asarray(vals[0, 1]))
 
 
 # ---------------------------------------------------------------------------
 # kernel vs reference oracle
 # ---------------------------------------------------------------------------
 
-def _pool_case(key, b, kv, g, dh, page, nb, bits):
+def _lane_dense(x, page, width):
+    """(stack, n_pages, page, ...) values -> the stacked lane-dense pool
+    leaf the kernels read (``kernels.kv_pages``)."""
+    return to_rows(x.reshape(*x.shape[:3], width), PageFormat(page, width))
+
+
+def _quantized_pools(kf, vf, bits, page):
+    """Stacked (stack, P, page, KV, dh) K/V values -> lane-dense int8 /
+    int4 / f32 pool leaves and scales (or None)."""
+    kv, dh = kf.shape[-2:]
+    if bits < 32:
+        kq, ksc = quantize_kv(kf, bits)
+        vq, vsc = quantize_kv(vf, bits)
+        ksc, vsc = _lane_dense(ksc, page, kv), _lane_dense(vsc, page, kv)
+    else:
+        kq, vq, ksc, vsc = kf, vf, None, None
+    w = kv * kq.shape[-1]
+    return _lane_dense(kq, page, w), _lane_dense(vq, page, w), ksc, vsc
+
+
+def _pool_case(key, b, kv, g, dh, page, nb, bits, stack=1):
     """Random page pool + table + ragged fill levels for one case."""
     n_pages = 1 + b * nb
     ks = jax.random.split(key, 3)
     q = jax.random.normal(ks[0], (b, kv, g, dh), jnp.float32)
-    kf = jax.random.normal(ks[1], (n_pages, page, kv, dh), jnp.float32)
-    vf = jax.random.normal(ks[2], (n_pages, page, kv, dh), jnp.float32)
-    if bits < 32:
-        kq, ksc = quantize_kv(kf, bits)
-        vq, vsc = quantize_kv(vf, bits)
-    else:
-        kq, vq, ksc, vsc = kf, vf, None, None
+    kf = jax.random.normal(ks[1], (stack, n_pages, page, kv, dh), jnp.float32)
+    vf = jax.random.normal(ks[2], (stack, n_pages, page, kv, dh), jnp.float32)
+    kq, vq, ksc, vsc = _quantized_pools(kf, vf, bits, page)
     table = jnp.arange(1, 1 + b * nb, dtype=jnp.int32).reshape(b, nb)
     kv_len = (jax.random.randint(jax.random.fold_in(key, 9), (b,), 1,
                                  nb * page + 1).astype(jnp.int32))
@@ -120,7 +147,7 @@ CONTIG = (1, 128, 129, 256)
 
 
 def _walk_case(key, bits, g, fills, *, kv=4, dh=16, page=WALK_PAGE,
-               nb=WALK_NB, parked=False):
+               nb=WALK_NB, parked=False, stack=1):
     """A page pool whose rows hold ``fills`` tokens in shuffled pages,
     the rest of each table pointing at the trash page; ``parked`` adds a
     last row as the scheduler sends a parked slot (an all-trash table,
@@ -130,13 +157,9 @@ def _walk_case(key, bits, g, fills, *, kv=4, dh=16, page=WALK_PAGE,
     b = len(fills) + parked
     ks = jax.random.split(key, 4)
     q = jax.random.normal(ks[0], (b, kv, g, dh), jnp.float32)
-    kf = jax.random.normal(ks[1], (n_pages, page, kv, dh), jnp.float32)
-    vf = jax.random.normal(ks[2], (n_pages, page, kv, dh), jnp.float32)
-    if bits < 32:
-        kq, ksc = quantize_kv(kf, bits)
-        vq, vsc = quantize_kv(vf, bits)
-    else:
-        kq, vq, ksc, vsc = kf, vf, None, None
+    kf = jax.random.normal(ks[1], (stack, n_pages, page, kv, dh), jnp.float32)
+    vf = jax.random.normal(ks[2], (stack, n_pages, page, kv, dh), jnp.float32)
+    kq, vq, ksc, vsc = _quantized_pools(kf, vf, bits, page)
     ids = 1 + np.asarray(jax.random.permutation(ks[3], n_pages - 1))
     table = np.zeros((b, nb), np.int32)
     starts = np.cumsum([0] + n_live)
@@ -154,7 +177,7 @@ def _contiguous_read(bits, g, fills, window, softcap, *, kv=4, dh=16):
     q = jax.random.normal(ks[0], (b, 1, kv * g, dh), jnp.float32)
     cache = {}
     for name, k in (("k", ks[1]), ("v", ks[2])):
-        x = jax.random.normal(k, (b, CONTIG_T, kv, dh), jnp.float32)
+        x = jax.random.normal(k, (1, b, CONTIG_T, kv, dh), jnp.float32)
         if bits < 32:
             cache[name], cache[name + "_scale"] = quantize_kv(x, bits)
         else:
@@ -203,9 +226,11 @@ def test_kernel_matches_ref(bits, g, window, softcap, block_kv, fills,
         case = (_pool_case(key, 2, 4, g, 16, 4, 3, bits) if fills is None
                 else _walk_case(key, bits, g, fills,
                                 parked=layout == "parked"))
-        got = paged_attention(*case, window=window, softcap=softcap,
-                              block_kv=block_kv)
-        want = paged_attention_ref(*case, window=window, softcap=softcap)
+        page = 4 if fills is None else WALK_PAGE
+        got = paged_attention(*case, page=page, window=window,
+                              softcap=softcap, block_kv=block_kv)
+        want = paged_attention_ref(*case, page=page, window=window,
+                                   softcap=softcap)
     got, want = np.asarray(got), np.asarray(want)
     if layout == "parked":
         # the reference walks the trash page for the parked row; the
@@ -216,6 +241,95 @@ def test_kernel_matches_ref(bits, g, window, softcap, block_kv, fills,
     np.testing.assert_allclose(got, want, atol=1e-5, rtol=1e-5)
 
 
+# the stacked pool read in place: a layer other than 0 of a 3-layer stack,
+# at head dims whose tokens share a 128-lane row (dh 16: 4 heads x 16 =
+# 64 values) or fill one (dh 32: 128 values, int4 64)
+STACKED = [pytest.param(bits, g, dh, window, softcap, parked,
+                        id=f"{name}-g{g}-dh{dh}" + ("-window-softcap"
+                                                     if window else "")
+                        + ("-parked" if parked else ""))
+           for bits, name in ((8, "int8"), (4, "int4"), (32, "f32"))
+           for g in (1, 2) for dh in (16, 32)
+           for window, softcap, parked in ((None, 0.0, True),
+                                           (40, 30.0, False))]
+
+
+@pytest.mark.parametrize("bits,g,dh,window,softcap,parked", STACKED)
+def test_kernel_reads_stacked_pool_at_layer(bits, g, dh, window, softcap,
+                                            parked):
+    """Fills of 1, 16, 129 and 320 tokens (and a parked row) in a stacked
+    lane-dense pool, read at layer 2 of 3 against the oracle."""
+    key = jax.random.PRNGKey(100 + bits * 7 + g + dh)
+    case = _walk_case(key, bits, g, (1, 16, 129, 320), dh=dh,
+                      parked=parked, stack=3)
+    kw = dict(page=WALK_PAGE, layer=2, window=window, softcap=softcap)
+    got = np.asarray(paged_attention(*case, **kw))
+    want = np.asarray(paged_attention_ref(*case, **kw))
+    if parked:
+        np.testing.assert_array_equal(got[-1], 0.0)
+        got, want = got[:-1], want[:-1]
+    np.testing.assert_allclose(got, want, atol=1e-5, rtol=1e-5)
+    # another layer's pages give another answer: the layer index is read
+    other = np.asarray(paged_attention(*case, **dict(kw, layer=0)))
+    assert not np.allclose(other[:len(got)], got, atol=1e-3)
+
+
+@pytest.mark.parametrize("bits", [8, 4])
+def test_kernel_bf16_queries_match_ref(bits):
+    """bf16 queries against an int8 / int4 pool take the one-pass bf16
+    score dot; its products are exact, so the kernel still meets the
+    oracle at f32 tolerance."""
+    case = _walk_case(jax.random.PRNGKey(bits), bits, 2, (16, 129),
+                      dh=32, stack=2)
+    q = case[0].astype(jnp.bfloat16)
+    kw = dict(page=WALK_PAGE, layer=1)
+    got = paged_attention(q, *case[1:], **kw)
+    want = paged_attention_ref(q, *case[1:], **kw)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=1e-5, rtol=1e-5)
+
+
+@pytest.mark.parametrize("bits,kv,dh,page", [
+    (8, 4, 16, 4), (8, 32, 96, 16), (4, 4, 16, 3), (4, 2, 16, 8),
+    (32, 4, 32, 4)])
+@pytest.mark.parametrize("vector_index", [False, True])
+def test_write_then_read_round_trip(bits, kv, dh, page, vector_index):
+    """``_write_kv`` scatters a layer's new tokens into the lane-dense
+    stacked pool and ``_gather_read`` reads them back: the stored values
+    (rounded once) return at their positions, in that layer alone."""
+    b, nb, stack, layer, seq = 2, 3, 3, 1, 5
+    dt = {8: jnp.int8, 4: jnp.uint8, 32: jnp.float32}[bits]
+    dh_s = dh // 2 if bits == 4 else dh
+    tails = {"k": ((kv, dh_s), dt), "v": ((kv, dh_s), dt)}
+    if bits < 32:
+        tails.update(k_scale=((kv,), jnp.float32),
+                     v_scale=((kv,), jnp.float32))
+    table = jnp.asarray([[3, 1, 6], [2, 5, 4]], jnp.int32)
+    cache = {"pages": make_pool(tails, stack, 1 + b * nb, page),
+             "table": jnp.broadcast_to(table, (stack, b, nb))}
+    ks = jax.random.split(jax.random.PRNGKey(bits + page), 2)
+    k = jax.random.normal(ks[0], (b, seq, kv, dh), jnp.float32)
+    v = jax.random.normal(ks[1], (b, seq, kv, dh), jnp.float32)
+    idx = jnp.asarray([0, page + 1]) if vector_index else jnp.asarray(2)
+    new = _write_kv(cache, k, v, idx, bits, layer)
+    got_k, got_v = _gather_read(new, jnp.float32, layer)
+    if bits < 32:
+        want_k = dequantize_kv(*quantize_kv(k, bits), jnp.float32)
+        want_v = dequantize_kv(*quantize_kv(v, bits), jnp.float32)
+    else:
+        want_k, want_v = k, v
+    starts = np.broadcast_to(np.asarray(idx), (b,))
+    for row in range(b):
+        at = slice(int(starts[row]), int(starts[row]) + seq)
+        np.testing.assert_array_equal(np.asarray(got_k[row, at]),
+                                      np.asarray(want_k[row]))
+        np.testing.assert_array_equal(np.asarray(got_v[row, at]),
+                                      np.asarray(want_v[row]))
+    for other in (0, 2):                 # the other layers stay zero
+        for leaf in new["pages"].values():
+            assert not np.asarray(leaf[other]).any()
+
+
 def test_kernel_trash_page_stays_inert():
     """Blocks past a slot's fill level point at real-but-stale pages or
     the trash page; both must be masked identically."""
@@ -224,8 +338,8 @@ def test_kernel_trash_page_stays_inert():
     q, kq, vq, ksc, vsc, table, _ = case
     kv_len = jnp.asarray([page], jnp.int32)      # only block 0 is live
     trash_table = table.at[0, 1:].set(0)         # blocks 1.. -> trash page
-    a = paged_attention(q, kq, vq, ksc, vsc, table, kv_len)
-    t = paged_attention(q, kq, vq, ksc, vsc, trash_table, kv_len)
+    a = paged_attention(q, kq, vq, ksc, vsc, table, kv_len, page=page)
+    t = paged_attention(q, kq, vq, ksc, vsc, trash_table, kv_len, page=page)
     np.testing.assert_allclose(np.asarray(a), np.asarray(t), atol=1e-6)
 
 
@@ -296,23 +410,23 @@ def test_gather_backend_is_sanctioned():
 
 def test_pa_contracts_flag_bad_pools():
     from repro.analysis.contracts import validate_decode_state
-    pool = {"k": jnp.zeros((1, 4, 2, 2, 8), jnp.int8),
-            "v": jnp.zeros((1, 4, 2, 2, 8), jnp.int8),
-            "k_scale": jnp.zeros((1, 4, 2, 2), jnp.float32),
-            "v_scale": jnp.zeros((1, 4, 2, 2), jnp.float32)}
+    tails = {"k": ((2, 8), jnp.int8), "v": ((2, 8), jnp.int8),
+             "k_scale": ((2,), jnp.float32), "v_scale": ((2,), jnp.float32)}
+    pool = make_pool(tails, stack=1, n_pages=4, page=2)
     table = jnp.zeros((1, 3, 2), jnp.int32)
     good = {"cache": {"layer": {"pages": pool, "table": table}}}
     assert not [f for f in validate_decode_state(good, n_slots=3)
                 if f.severity == "error"]
     # PA1: k/v dtype disagreement
     bad = {"cache": {"layer": {
-        "pages": dict(pool, v=pool["v"].astype(jnp.uint8)),
+        "pages": pool.replace(v=pool["v"].astype(jnp.uint8)),
         "table": table}}}
     assert any(f.rule == "PA1" for f in validate_decode_state(bad)
                if f.severity == "error")
     # PA2: pool with only the trash page
     bad = {"cache": {"layer": {
-        "pages": {k: v[:, :1] for k, v in pool.items()}, "table": table}}}
+        "pages": make_pool(tails, stack=1, n_pages=1, page=2),
+        "table": table}}}
     assert any(f.rule == "PA2" for f in validate_decode_state(bad)
                if f.severity == "error")
     # PA3: live page after a trash-page hole

@@ -279,6 +279,7 @@ def test_paged_attention_kernel_matches_ref(case):
     """Fused Pallas decode kernel (in-kernel dequant, block-table walk)
     == jnp gather+softmax oracle for random pools, ragged per-slot fill
     levels, GQA ratios, kv-bits, windows, and block_kv tiles."""
+    from repro.kernels.kv_pages import PageFormat, to_rows
     from repro.kernels.paged_attention import paged_attention
     from repro.kernels.ref import paged_attention_ref
     from repro.models.attention import quantize_kv
@@ -286,22 +287,31 @@ def test_paged_attention_kernel_matches_ref(case):
     b, kv, g, dh, page, nb, bits, window, block_kv, seed = case
     if block_kv > kv or kv % block_kv:
         block_kv = 1
-    n_pages = 1 + b * nb
+    n_pages, stack, layer = 1 + b * nb, 2, seed % 2
     ks = jax.random.split(jax.random.PRNGKey(seed), 4)
     q = jax.random.normal(ks[0], (b, kv, g, dh), jnp.float32)
-    kf = jax.random.normal(ks[1], (n_pages, page, kv, dh), jnp.float32)
-    vf = jax.random.normal(ks[2], (n_pages, page, kv, dh), jnp.float32)
+    shape = (stack, n_pages, page, kv, dh)
+    kf = jax.random.normal(ks[1], shape, jnp.float32)
+    vf = jax.random.normal(ks[2], shape, jnp.float32)
     if bits < 32:
         kq, ksc = quantize_kv(kf, bits)
         vq, vsc = quantize_kv(vf, bits)
     else:
         kq, vq, ksc, vsc = kf, vf, None, None
+
+    def lane_dense(x):                   # the stored pool (kernels.kv_pages)
+        if x is None:
+            return None
+        w = int(np.prod(x.shape[3:]))
+        return to_rows(x.reshape(stack, n_pages, page, w),
+                       PageFormat(page, w))
+    kq, vq, ksc, vsc = map(lane_dense, (kq, vq, ksc, vsc))
     table = jnp.arange(1, 1 + b * nb, dtype=jnp.int32).reshape(b, nb)
     kv_len = jax.random.randint(ks[3], (b,), 1,
                                 nb * page + 1).astype(jnp.int32)
-    got = paged_attention(q, kq, vq, ksc, vsc, table, kv_len,
-                          window=window, block_kv=block_kv)
+    got = paged_attention(q, kq, vq, ksc, vsc, table, kv_len, page=page,
+                          layer=layer, window=window, block_kv=block_kv)
     want = paged_attention_ref(q, kq, vq, ksc, vsc, table, kv_len,
-                               window=window)
+                               page=page, layer=layer, window=window)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=2e-5, rtol=2e-5)

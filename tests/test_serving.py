@@ -361,8 +361,8 @@ for shape in [(2, 1), (1, 2)]:
     assert all(res[i].tokens == ref[i].tolist() for i in range(4)), shape
 print("SHARDED_OK")
 
-# paged cache placed via cache_pspecs (page pool on the data axes, KV
-# heads on the model axis, block tables replicated) must decode
+# paged cache placed via cache_pspecs (page pool on the data axes, block
+# tables replicated; a stored token row holds every KV head) must decode
 # token-identically to single-device, int8 and int4 at-rest
 for kv_bits in (8, 4):
     ref_res = ServeEngine(api, params, kv_quant_bits=kv_bits).serve(
@@ -427,7 +427,26 @@ for layout, kv_bits in (("packed", 8), ("bitplane", 4)):
     ref = ServeEngine(api, params, **kw).serve(reqs(), n_slots=3)
     with use_mesh(make_mesh((1, 2), ("data", "model"))):
         eng = ServeEngine(api, params, **kw)
-        res = eng.serve(reqs(), n_slots=3)
+        sched = eng.make_scheduler(reqs(), n_slots=3)
+        res = sched.run(reqs())
+    # K/V lanes split on 'model' where each device's share is whole heads
+    # in whole lane tiles (int8: 2 heads of 128 lanes, one a device);
+    # int4's 2 x 64 lanes, and the scales, stay whole on every device
+    pool = sched.state["cache"]["pages"]
+    for name, leaf in pool.items():
+        split = kv_bits == 8 and name in ("k", "v")
+        lanes = leaf.shape[-1] // (2 if split else 1)
+        assert all(s.data.shape == leaf.shape[:-1] + (lanes,)
+                   for s in leaf.addressable_shards), (name, kv_bits)
+    # and the decode program gathers no layer of the pool to one device
+    with use_mesh(eng.mesh):
+        hlo = eng.compiled_text("decode", jnp.zeros((3, 1), jnp.int32),
+                                sched.state, jnp.zeros((3,), jnp.int32))
+    layer_shapes = {",".join(map(str, leaf.shape[1:]))
+                    for leaf in pool.values()}
+    assert not [l for l in hlo.splitlines() if "all-gather(" in l
+                and any(f"[{s}]" in l or f",{s}]" in l
+                        for s in layer_shapes)], kv_bits
     lay = eng.params["layers"]
     splits = {n: lay[g][n].split for g in ("attn", "mlp") for n in lay[g]}
     assert splits == {"wq": "col", "wk": "col", "wv": "col", "wo": "row",
@@ -451,7 +470,9 @@ print("SHARDED_KERNELS_OK")
 def test_sharded_kernels_match_single_device():
     """Under a (data=1, model=2) mesh the Pallas matmuls split by output
     columns or input rows and the paged-attention kernel by KV heads
-    (``shard_map``, since Mosaic kernels cannot be auto-partitioned);
+    (``shard_map``, since Mosaic kernels cannot be auto-partitioned),
+    each device holding its heads' lanes of the page pool where they
+    are whole lane tiles and gathering no layer of it;
     under (data=2, model=1) the matmuls split their rows.  The served
     tokens equal the single-device engine's, and the fused attention
     kernel refuses a data-sharded mesh."""

@@ -197,3 +197,35 @@ def test_hlo_collective_parser():
     assert st.counts == {"all-reduce": 1, "all-gather": 1}
     assert st.bytes_by_op["all-reduce"] == 256 * 512 * 4
     assert st.bytes_by_op["all-gather"] == 1024 * 64 * 2
+
+
+@pytest.mark.parametrize("kv,dh,bits,split", [
+    (32, 96, 8, True),       # phi3 int8: 8 heads x 96 = 768 lanes a chip
+    (32, 96, 4, True),       # phi3 int4: 8 heads x 48 = 384 lanes a chip
+    (2, 128, 4, False),      # 1 head x 64 lanes: not a whole lane tile
+    (6, 128, 8, False),      # 6 heads do not split 4 ways
+])
+def test_paged_pool_lanes_split_by_heads(kv, dh, bits, split):
+    """On a (data=1, model=4) mesh a page pool's K/V lanes shard on
+    'model' where each chip's share is whole KV heads in whole lane
+    tiles; the per-token scales stay whole on every chip."""
+    from repro.kernels.kv_pages import make_pool
+
+    class _Mesh14:
+        shape = {"data": 1, "model": 4}
+        axis_names = ("data", "model")
+
+    dt, w = (jnp.int8, dh) if bits == 8 else (jnp.uint8, dh // 2)
+    pool = jax.eval_shape(lambda: make_pool(
+        {"k": ((kv, w), dt), "v": ((kv, w), dt),
+         "k_scale": ((kv,), jnp.float32), "v_scale": ((kv,), jnp.float32)},
+        2, 65, 16))
+    state = {"cache": {"pages": pool,
+                       "table": jax.ShapeDtypeStruct((2, 4, 16), jnp.int32)}}
+    with use_mesh(_Mesh14()):
+        cs = cache_pspecs(state, batch_size=4, pad=False)["cache"]
+    lanes = "model" if split else None
+    assert cs["pages"]["k"] == P(None, "data", None, lanes)
+    assert cs["pages"]["v"] == P(None, "data", None, lanes)
+    assert cs["pages"]["k_scale"] == P(None, "data", None, None)
+    assert cs["table"] == P(None, None, None)
